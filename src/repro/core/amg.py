@@ -14,7 +14,7 @@ import numpy as np
 import scipy.sparse as sp
 
 from .laplacian import Graph, grounded_laplacian_coo
-from .spai import EllPrecond, dense_to_ell
+from .spai import EllPrecond, matrix_to_ell
 
 
 def _laplacian_csr(g: Graph) -> sp.csr_matrix:
@@ -155,6 +155,6 @@ def amg_ell_precond(g: Graph, *, droptol: float = 1e-3,
     # actually acts — SPD on the mean-zero subspace.
     M = M - M.mean(axis=1, keepdims=True) - M.mean(axis=0, keepdims=True) \
         + M.mean()
-    out = dense_to_ell(M, droptol=droptol, dtype=dtype)
+    out = matrix_to_ell(M, droptol=droptol, dtype=dtype)
     out.meta.update(family="amg")
     return out

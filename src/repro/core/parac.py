@@ -35,6 +35,13 @@ sampled edges are dropped *and counted* — `strict=True` retries with a
 doubled slack instead (dynamic malloc is as ill-advised in XLA as in
 device code).  In the batched path only the overflowing graphs re-run
 (masked re-runs at doubled slack); converged graphs keep their result.
+
+The doubling is bounded: eliminating a vertex with ``d`` merged
+neighbours removes its ``d`` edges and samples ``d − 1``, so the graph
+never holds more than ``m`` live edges, and a slab can never need more
+than ``m`` slots.  At ``fill_slack ≥ m`` nothing overflows
+(:func:`_next_strict_slack`); a strict run that still drops an edge
+there raises instead of returning a factor with dropped fill.
 """
 from __future__ import annotations
 
@@ -288,6 +295,12 @@ def _cumcount(keys: np.ndarray, n: int) -> np.ndarray:
     return rank
 
 
+@partial(jax.jit, static_argnames=("n", "nnz"))
+def _factor_slices(col_ptr, rows, vals, D, *, n: int, nnz: int):
+    """The compacted pool cut to the factor's own ``n`` and ``nnz``."""
+    return col_ptr[:n + 1], rows[:nnz], vals[:nnz], D[:n]
+
+
 def _finalize_factor(g: Graph, final: EngineState, col_base: jnp.ndarray,
                      *, n_phantom: int = 0, stats: dict) -> ACFactor:
     """Compact the engine pool on device and wrap it as an ``ACFactor``.
@@ -306,10 +319,8 @@ def _finalize_factor(g: Graph, final: EngineState, col_base: jnp.ndarray,
     rows_c, vals_c, col_ptr_d = _compact_pool(
         final.pool_row, final.pool_val, final.col_fill, col_base)
     nnz = int(col_ptr_d[n])
-    col_ptr_g = jax.lax.slice(col_ptr_d, (0,), (n + 1,))
-    rows_dev = jax.lax.slice(rows_c, (0,), (nnz,))
-    vals_dev = jax.lax.slice(vals_c, (0,), (nnz,))
-    D_dev = jax.lax.slice(final.D, (0,), (n,))
+    col_ptr_g, rows_dev, vals_dev, D_dev = _factor_slices(
+        col_ptr_d, rows_c, vals_c, final.D, n=n, nnz=nnz)
     dev = DeviceFactor(col_ptr=col_ptr_g, rows=rows_dev, vals=vals_dev,
                        D=D_dev)
     return ACFactor(n=n, col_ptr=np.asarray(col_ptr_g).astype(np.int64),
@@ -317,15 +328,29 @@ def _finalize_factor(g: Graph, final: EngineState, col_base: jnp.ndarray,
                     D=np.asarray(D_dev), stats=stats, device=dev)
 
 
+def _next_strict_slack(g: Graph, slack: int, overflow: int) -> int:
+    """The slack of a strict retry after ``overflow`` dropped edges.  A
+    slab holds live edges only and the graph never holds more than
+    ``m``, so at ``fill_slack ≥ m`` no edge can be dropped."""
+    if slack >= max(g.m, 1):
+        raise RuntimeError(
+            f"strict factorization dropped {overflow} sampled edges at "
+            f"fill_slack={slack} >= m={g.m}, where no slab can overflow")
+    return slack * 2
+
+
 def factorize_wavefront(g: Graph, key: jax.Array, *, chunk: int = 64,
                         fill_slack: int = 32, strict: bool = True,
-                        max_retries: int = 3,
                         dtype=np.float32) -> ACFactor:
     """Parallel ParAC factorization.  Returns the same ``ACFactor`` as the
-    sequential oracle (bit-identical for the same key when no overflow)."""
+    sequential oracle (bit-identical for the same key when no overflow).
+
+    ``strict`` re-runs at doubled ``fill_slack`` until no sampled edge is
+    dropped, so a strict factor always has ``overflow == 0``; without it
+    one run is made and its drops are counted in ``stats``."""
     n = g.n
     slack = fill_slack
-    for attempt in range(max_retries + 1):
+    while True:
         (pool_row, pool_val, fill, dep, col_base, cap, P, dmax) = \
             _build_pool(g, slack, dtype)
         final = _run_engine(
@@ -333,9 +358,9 @@ def factorize_wavefront(g: Graph, key: jax.Array, *, chunk: int = 64,
             jnp.asarray(dep), jnp.asarray(col_base), jnp.asarray(cap), key,
             dmax=dmax, chunk=min(chunk, max(n, 1)))
         ovf = int(final.overflow)
-        if ovf == 0 or not strict or attempt == max_retries:
+        if ovf == 0 or not strict:
             break
-        slack *= 2
+        slack = _next_strict_slack(g, slack, ovf)
     stats = dict(rounds=int(final.n_rounds), overflow=ovf,
                  chunk=chunk, fill_slack=slack, pool_size=P, dmax=dmax)
     return _finalize_factor(g, final, jnp.asarray(col_base), stats=stats)
@@ -357,7 +382,7 @@ def _pad_np(x: np.ndarray, size: int, fill) -> np.ndarray:
 
 def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
                       fill_slack: int = 32, strict: bool = True,
-                      max_retries: int = 3, dtype=np.float32,
+                      dtype=np.float32,
                       bucket: bool = True, with_schedules: bool = False,
                       device: Optional[jax.Device] = None):
     """Factor a fleet of Laplacians concurrently in one XLA program.
@@ -372,7 +397,8 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
 
     Overflow is handled per graph: converged graphs keep their factor
     while the overflowing subset re-runs at doubled slack (masked
-    re-runs), mirroring the single-graph strict retry loop.
+    re-runs), mirroring the single-graph strict retry loop — a strict
+    factor never carries ``overflow > 0``.
 
     With ``with_schedules`` the fleet's triangular level schedules are
     also derived in one vmapped pass (``trisolve.build_schedules_batched``
@@ -390,8 +416,8 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
         with jax.default_device(device):
             return factorize_batched(
                 gs, keys, chunk=chunk, fill_slack=fill_slack,
-                strict=strict, max_retries=max_retries, dtype=dtype,
-                bucket=bucket, with_schedules=with_schedules)
+                strict=strict, dtype=dtype, bucket=bucket,
+                with_schedules=with_schedules)
     gs = list(gs)
     B = len(gs)
     if not isinstance(keys, jax.Array):
@@ -404,7 +430,7 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
     slacks = [fill_slack] * B
     results: List[Optional[ACFactor]] = [None] * B
     pending = list(range(B))
-    for attempt in range(max_retries + 1):
+    while pending:
         built = {i: _build_pool(gs[i], slacks[i], dtype) for i in pending}
         n_pad = max(max(gs[i].n for i in pending), 1)
         P_pad = max(max(built[i][6] for i in pending), 1)
@@ -440,7 +466,7 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
         for bi, i in enumerate(pending):
             final_i = jax.tree_util.tree_map(lambda x, bi=bi: x[bi], out)
             ovf = int(final_i.overflow)
-            if ovf == 0 or not strict or attempt == max_retries:
+            if ovf == 0 or not strict:
                 stats = dict(rounds=int(final_i.n_rounds), overflow=ovf,
                              chunk=chunk, fill_slack=slacks[i],
                              pool_size=int(built[i][6]),
@@ -451,11 +477,9 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
                     gs[i], final_i, jnp.asarray(CB[bi]),
                     n_phantom=n_pad - gs[i].n, stats=stats)
             else:
-                slacks[i] *= 2
+                slacks[i] = _next_strict_slack(gs[i], slacks[i], ovf)
                 retry.append(i)
         pending = retry
-        if not pending:
-            break
     if not with_schedules:
         return results
     from .trisolve import build_schedules_batched

@@ -3,12 +3,16 @@
 
 Laplacian systems are singular with nullspace span(1); both solvers keep
 iterates mean-zero (standard projection, same as the paper's experimental
-setup which reports relative residuals on Laplacian systems).
+setup which reports relative residuals on Laplacian systems).  The JAX
+solvers also re-project every updated residual: in float32 the rounding
+of ``L·p`` leaves a constant residual component that the factor's
+preconditioner amplifies, and on the SUITE_LARGE 2D grid it held the
+recurrence residual at 1–2e-6.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, NamedTuple
 
 import numpy as np
 import jax
@@ -69,6 +73,8 @@ def pcg_jax(matvec: Callable, precond: Callable, b: jnp.ndarray, *,
         alpha = rz / jnp.vdot(p, Ap)
         x = x + alpha * p
         r = r - alpha * Ap
+        if project:
+            r = r - jnp.mean(r)
         z = precond(r)
         if project:
             z = z - jnp.mean(z)
@@ -122,7 +128,7 @@ def _pcg_batched_body(matvec: Callable, precond: Callable, *, tol, maxiter,
         pAp = jnp.sum(P * AP, axis=1)
         alpha = jnp.where(active, rz / jnp.where(pAp != 0, pAp, 1.0), 0.0)
         Xn = X + alpha[:, None] * P
-        Rn = R - alpha[:, None] * AP
+        Rn = _proj(R - alpha[:, None] * AP)
         Zn = _proj(precond(Rn))
         rz_new = jnp.sum(Rn * Zn, axis=1)
         beta = jnp.where(active, rz_new / jnp.where(rz != 0, rz, 1.0), 0.0)
@@ -198,24 +204,34 @@ def pcg_jax_batched(matvec: Callable, precond: Callable, B: jnp.ndarray, *,
 class FleetArrays(NamedTuple):
     """Stacked, bucket-padded device factors — the **traced** factor
     argument of the fleet PCG programs.  Row ``f`` holds one factor's
-    padded Laplacian edge lists, row-indexed forward/backward trisolve
-    panels, inverse diagonal and true size; a lane gathers its factor by
-    index, so every factor whose padded shapes match shares one compiled
-    step program (the factor is data, not a closure constant)."""
+    padded Laplacian edge lists, forward/backward trisolve panels (in
+    the order of their sweep plans), inverse diagonal and true size; a
+    lane gathers its factor by index, so every factor whose padded
+    shapes match shares one compiled step program (the factor is data, not a closure constant)."""
 
     src: jnp.ndarray      # int32[F, m_pad] — Laplacian edges (0-padded)
     dst: jnp.ndarray      # int32[F, m_pad]
     w: jnp.ndarray        # f32[F, m_pad]   (0 on padding)
-    fcols: jnp.ndarray    # int32[F, n_pad, Kf] — fwd panels, row-indexed
-    fvals: jnp.ndarray    # f32[F, n_pad, Kf]
+    fcols: jnp.ndarray    # int32[F, n_pad, Kf] — fwd panels, in the order
+    fvals: jnp.ndarray    # f32[F, n_pad, Kf]     of forder (spmv: by row)
     flevel: jnp.ndarray   # int32[F, n_pad]
-    bcols: jnp.ndarray    # int32[F, n_pad, Kb] — bwd panels (unflipped)
-    bvals: jnp.ndarray    # f32[F, n_pad, Kb]
+    bcols: jnp.ndarray    # int32[F, n_pad, Kb] — bwd panels (unflipped),
+    bvals: jnp.ndarray    # f32[F, n_pad, Kb]     in the order of border
     blevel: jnp.ndarray   # int32[F, n_pad]
     dinv: jnp.ndarray     # f32[F, n_pad]  — 1/D (0 where D <= 0 / phantom)
     nvalid: jnp.ndarray   # int32[F]       — true vertex count per factor
     fnlv: jnp.ndarray     # int32[F]       — true fwd level count per factor
     bnlv: jnp.ndarray     # int32[F]       — true bwd level count per factor
+    # sweep plans of the fwd/bwd panels (``kernels.ops.sweep_plan``);
+    # the level pointers span the bucket-wide level bound
+    forder: jnp.ndarray   # int32[F, n_pad]
+    fext: jnp.ndarray     # int32[F, n_pad]
+    fgend: jnp.ndarray    # int32[F, n_pad]
+    fptr: jnp.ndarray     # int32[F, f_levels + 1]
+    border: jnp.ndarray   # int32[F, n_pad]
+    bext: jnp.ndarray     # int32[F, n_pad]
+    bgend: jnp.ndarray    # int32[F, n_pad]
+    bptr: jnp.ndarray     # int32[F, b_levels + 1]
 
 
 class FleetPCGState(NamedTuple):
@@ -256,8 +272,7 @@ def fleet_matvec(fa: FleetArrays, fidx: jnp.ndarray,
 
 def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
                        *, f_levels: int, b_levels: int,
-                       kind: str = "factor",
-                       interpret: Optional[bool] = None,
+                       kind: str = "factor", f_width=None, b_width=None,
                        active=None) -> jnp.ndarray:
     """Per-lane preconditioner apply, dispatched on the **static** apply
     ``kind`` of the family that owns the fleet:
@@ -274,7 +289,13 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
       Used by SPAI and the flattened AMG operator — a single kernel
       launch per apply instead of ``f_levels + b_levels`` masked sweeps.
 
-    ``kind`` must be static under jit (it selects the traced program).
+    ``kind`` must be static under jit (it selects the traced program),
+    as must ``f_width``/``b_width``: the rows of one trisolve sweep
+    (``kernels.ops.trisolve_fleet``'s ``width``, the largest
+    ``PackedSchedule.sweep_width`` of any fleet member; ``None`` sweeps
+    a whole level at a time).  The sweeps walk the fleet's stored sweep
+    plans (``forder``/``fext``/``fgend``/``fptr`` and their backward
+    twins).
 
     The static ``f_levels``/``b_levels`` ceilings bound compilation; the
     *trip count* of each trisolve is further bounded dynamically by the
@@ -288,8 +309,7 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
     # top-level import here is a cycle whenever kernels.ops loads first
     from repro.kernels.ops import ell_spmv_fleet, trisolve_fleet
     if kind == "spmv":
-        return ell_spmv_fleet(fa.fcols[fidx], fa.fvals[fidx], R,
-                              interpret=interpret)
+        return ell_spmv_fleet(fa.fcols[fidx], fa.fvals[fidx], R)
     if kind != "factor":
         raise ValueError(f"unknown preconditioner apply kind: {kind!r}")
     flv = fa.fnlv[fidx]
@@ -297,13 +317,16 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
     if active is not None:
         flv = jnp.where(active, flv, 1)
         blv = jnp.where(active, blv, 1)
-    Y = trisolve_fleet(fa.fcols[fidx], fa.fvals[fidx], fa.flevel[fidx], R,
-                       n_levels=f_levels, interpret=interpret,
-                       lane_levels=flv)
+    Y = trisolve_fleet(fa.fcols, fa.fvals, None, R, fidx=fidx,
+                       n_levels=f_levels, lane_levels=flv, width=f_width,
+                       plan=(fa.forder[fidx], fa.fext[fidx], fa.fgend[fidx],
+                             fa.fptr[fidx, :f_levels + 1]))
     Z = Y * fa.dinv[fidx]
-    return trisolve_fleet(fa.bcols[fidx], fa.bvals[fidx], fa.blevel[fidx],
-                          Z, n_levels=b_levels, interpret=interpret,
-                          lane_levels=blv)
+    return trisolve_fleet(fa.bcols, fa.bvals, None, Z, fidx=fidx,
+                          n_levels=b_levels, lane_levels=blv,
+                          width=b_width,
+                          plan=(fa.border[fidx], fa.bext[fidx],
+                                fa.bgend[fidx], fa.bptr[fidx, :b_levels + 1]))
 
 
 def _fleet_project(Y: jnp.ndarray, nvalid: jnp.ndarray) -> jnp.ndarray:
@@ -319,8 +342,8 @@ def _fleet_project(Y: jnp.ndarray, nvalid: jnp.ndarray) -> jnp.ndarray:
 
 def pcg_fleet_init(fa: FleetArrays, fidx, B, tol, maxiter, *,
                    f_levels: int, b_levels: int, kind: str = "factor",
-                   project: bool = True,
-                   interpret: Optional[bool] = None) -> FleetPCGState:
+                   f_width=None, b_width=None,
+                   project: bool = True) -> FleetPCGState:
     """Set up the fleet PCG carry for columns ``B`` of shape
     ``(L, n_pad)`` (each zero-padded past its factor's true n).  ``tol``
     and ``maxiter`` are per-lane arrays; lane ``l`` solves against
@@ -334,8 +357,8 @@ def pcg_fleet_init(fa: FleetArrays, fidx, B, tol, maxiter, *,
     bnorm = jnp.where(bnorm > 0, bnorm, 1.0)
     R0 = B
     Z0 = fleet_precondition(fa, fidx, R0, f_levels=f_levels,
-                            b_levels=b_levels, kind=kind,
-                            interpret=interpret)
+                            b_levels=b_levels, kind=kind, f_width=f_width,
+                            b_width=b_width)
     if project:
         Z0 = _fleet_project(Z0, nvalid)
     rz0 = jnp.sum(R0 * Z0, axis=1)
@@ -349,8 +372,8 @@ def pcg_fleet_init(fa: FleetArrays, fidx, B, tol, maxiter, *,
 
 
 def _pcg_fleet_body(fa: FleetArrays, *, f_levels: int, b_levels: int,
-                    kind: str = "factor", project: bool,
-                    interpret: Optional[bool] = None):
+                    kind: str = "factor", f_width=None, b_width=None,
+                    project: bool):
     """One frozen-lane fleet PCG iteration as a pure
     ``FleetPCGState -> FleetPCGState`` closure over the **traced** fleet
     arrays — the factor-as-data restatement of ``_pcg_batched_body``.
@@ -365,9 +388,15 @@ def _pcg_fleet_body(fa: FleetArrays, *, f_levels: int, b_levels: int,
                           s.rz / jnp.where(pAp != 0, pAp, 1.0), 0.0)
         Xn = s.X + alpha[:, None] * s.P
         Rn = s.R - alpha[:, None] * AP
+        if project:
+            # L·P is mean-zero only up to rounding, and the factor's
+            # preconditioner amplifies a constant residual component:
+            # left in, it stalls the float32 recurrence near 1e-6
+            Rn = _fleet_project(Rn, nvalid)
         Zn = fleet_precondition(fa, s.fidx, Rn, f_levels=f_levels,
                                 b_levels=b_levels, kind=kind,
-                                interpret=interpret, active=s.active)
+                                f_width=f_width, b_width=b_width,
+                                active=s.active)
         if project:
             Zn = _fleet_project(Zn, nvalid)
         rz_new = jnp.sum(Rn * Zn, axis=1)
@@ -392,13 +421,14 @@ def _pcg_fleet_body(fa: FleetArrays, *, f_levels: int, b_levels: int,
 
 def pcg_fleet_step(fa: FleetArrays, state: FleetPCGState, *, k: int,
                    f_levels: int, b_levels: int, kind: str = "factor",
-                   project: bool = True,
-                   interpret: Optional[bool] = None) -> FleetPCGState:
+                   f_width=None, b_width=None,
+                   project: bool = True) -> FleetPCGState:
     """Advance every active lane by up to ``k`` iterations (early exit
     when all lanes freeze).  Step slicing is exact, as in
     ``pcg_batched_step``."""
     body = _pcg_fleet_body(fa, f_levels=f_levels, b_levels=b_levels,
-                           kind=kind, project=project, interpret=interpret)
+                           kind=kind, f_width=f_width, b_width=b_width,
+                           project=project)
 
     def cond(c):
         s, j = c
@@ -414,16 +444,17 @@ def pcg_fleet_step(fa: FleetArrays, state: FleetPCGState, *, k: int,
 
 def pcg_fleet_solve(fa: FleetArrays, fidx, B, tol, maxiter, *,
                     f_levels: int, b_levels: int, kind: str = "factor",
-                    project: bool = True,
-                    interpret: Optional[bool] = None) -> FleetPCGState:
+                    f_width=None, b_width=None,
+                    project: bool = True) -> FleetPCGState:
     """One-shot fleet solve: init then iterate until every lane freezes.
     Runs the same body as ``pcg_fleet_step``, so an engine slicing the
     same solve into ticks takes bit-identical per-lane iterates."""
     state = pcg_fleet_init(fa, fidx, B, tol, maxiter, f_levels=f_levels,
-                           b_levels=b_levels, kind=kind, project=project,
-                           interpret=interpret)
+                           b_levels=b_levels, kind=kind, f_width=f_width,
+                           b_width=b_width, project=project)
     body = _pcg_fleet_body(fa, f_levels=f_levels, b_levels=b_levels,
-                           kind=kind, project=project, interpret=interpret)
+                           kind=kind, f_width=f_width, b_width=b_width,
+                           project=project)
     return jax.lax.while_loop(lambda s: jnp.any(s.active), body, state)
 
 

@@ -36,6 +36,7 @@ of accumulating near-duplicates under the budget.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 import hashlib
 import heapq
 import time
@@ -162,9 +163,9 @@ register_family(
     # ``FactorCache.factor`` (it alone batches through
     # ``factorize_batched``); this builder is the single-graph path
     lambda g, key, *, dtype=np.float32, chunk=64, fill_slack=32,
-    strict=True, max_retries=3: factorize_wavefront(
+    strict=True: factorize_wavefront(
         g, key, chunk=chunk, fill_slack=fill_slack, strict=strict,
-        max_retries=max_retries, dtype=dtype))
+        dtype=dtype))
 register_family(
     "ichol", "factor",
     lambda g, key, *, dtype=np.float32, droptol=0.0, max_shift_tries=8:
@@ -193,6 +194,15 @@ def _grow(x: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
     return jnp.pad(x, [(0, t - s) for s, t in zip(x.shape, shape)])
 
 
+@partial(jax.jit, static_argnames=("m_pad", "n_pad"))
+def _padded_edges(src, dst, w, D, *, m_pad: int, n_pad: int):
+    """A factor's Laplacian edge lists zero-padded to ``m_pad`` and its
+    inverse diagonal (0 where ``D <= 0``) to ``n_pad``."""
+    dinv = jnp.where(D > 0, 1.0 / jnp.where(D > 0, D, 1.0), 0.0)
+    return (_pad1(src, m_pad), _pad1(dst, m_pad), _pad1(w, m_pad),
+            _pad1(dinv, n_pad))
+
+
 class _PaddedFactor:
     """One preconditioner's bucket-padded device arrays, ready for fleet
     admission: padded Laplacian edge lists, forward/backward
@@ -210,13 +220,10 @@ class _PaddedFactor:
         self.n = g.n
         self.n_pad = fwd.n_pad
         m_pad = max(_next_pow2(g.m), 1)
-        with jax.ensure_compile_time_eval():
-            self.src = _pad1(jnp.asarray(g.src, jnp.int32), m_pad)
-            self.dst = _pad1(jnp.asarray(g.dst, jnp.int32), m_pad)
-            self.w = _pad1(jnp.asarray(g.w, dev.vals.dtype), m_pad)
-            D = dev.D
-            dinv = jnp.where(D > 0, 1.0 / jnp.where(D > 0, D, 1.0), 0.0)
-            self.dinv = _pad1(dinv, self.n_pad)
+        w = np.asarray(g.w).astype(dev.vals.dtype)
+        self.src, self.dst, self.w, self.dinv = _padded_edges(
+            np.asarray(g.src, np.int32), np.asarray(g.dst, np.int32), w,
+            dev.D, m_pad=m_pad, n_pad=self.n_pad)
         self.fwd = fwd
         self.bwd = bwd
 
@@ -231,18 +238,67 @@ class _PaddedFactor:
             cols = _grow(jnp.asarray(op.cols, jnp.int32), (n_pad, op.K))
             vals = _grow(jnp.asarray(op.vals), (n_pad, op.K))
             zeros_n = jnp.zeros((n_pad,), jnp.int32)
+            # one level: the plan is the identity order (never swept)
+            plan = dict(order=jnp.arange(n_pad, dtype=jnp.int32),
+                        extent=zeros_n,
+                        group_end=jnp.full((n_pad,), n_pad, jnp.int32),
+                        level_ptr=jnp.asarray([0, n_pad], jnp.int32))
             fwd = PackedSchedule(n=g.n, n_pad=n_pad, n_levels=1, K=op.K,
-                                 cols=cols, vals=vals, level_of=zeros_n)
+                                 cols=cols, vals=vals, level_of=zeros_n,
+                                 **plan)
             bwd = PackedSchedule(
                 n=g.n, n_pad=n_pad, n_levels=1, K=1,
                 cols=jnp.zeros((n_pad, 1), jnp.int32),
                 vals=jnp.zeros((n_pad, 1), vals.dtype),
-                level_of=zeros_n)
+                level_of=zeros_n, **plan)
             dev = DeviceFactor(col_ptr=jnp.zeros((g.n + 1,), jnp.int32),
                                rows=jnp.zeros((0,), jnp.int32),
                                vals=jnp.zeros((0,), vals.dtype),
                                D=jnp.zeros((g.n,), vals.dtype))
         return cls(g, dev, fwd, bwd)
+
+
+def _row_payload(pf: _PaddedFactor) -> FleetArrays:
+    """One factor's fleet row, field by field, before padding: panels
+    row-indexed (``_fleet_admit`` puts them in sweep-plan order)."""
+    return FleetArrays(
+        src=pf.src, dst=pf.dst, w=pf.w,
+        fcols=pf.fwd.cols, fvals=pf.fwd.vals, flevel=pf.fwd.level_of,
+        bcols=pf.bwd.cols, bvals=pf.bwd.vals, blevel=pf.bwd.level_of,
+        dinv=pf.dinv, nvalid=np.int32(pf.n),
+        fnlv=np.int32(pf.fwd.n_levels), bnlv=np.int32(pf.bwd.n_levels),
+        forder=pf.fwd.order, fext=pf.fwd.extent, fgend=pf.fwd.group_end,
+        fptr=pf.fwd.level_ptr, border=pf.bwd.order, bext=pf.bwd.extent,
+        bgend=pf.bwd.group_end, bptr=pf.bwd.level_ptr)
+
+
+@partial(jax.jit, static_argnames=("shapes",))
+def _fleet_admit(a: Optional[FleetArrays], ix, rows, *, shapes):
+    """Grow a fleet stack to ``shapes`` (zero padding; level counts pad
+    with 1) and write ``rows`` (from ``_row_payload``) at stack rows
+    ``ix``, panels permuted into their sweep plans' order.  One program
+    per shape set: dispatched op by op, an admission compiled some
+    sixty primitives for every new bucket shape."""
+    ones = ("fnlv", "bnlv")
+    plan_of = {"fcols": "forder", "fvals": "forder",
+               "bcols": "border", "bvals": "border"}
+    out = {}
+    for name, shape in zip(FleetArrays._fields, shapes):
+        new = []
+        for r in rows:
+            x = jnp.asarray(getattr(r, name))
+            if name in plan_of:
+                x = jnp.take(x, getattr(r, plan_of[name]), axis=0)
+            new.append(_grow(x, shape[1:]))
+        new = jnp.stack(new)
+        if a is None:
+            base = jnp.full(shape, 1 if name in ones else 0, new.dtype)
+        else:
+            base = _grow(getattr(a, name), shape)
+            if name in ones:
+                base = jnp.maximum(base, 1)
+        out[name] = base.at[ix].set(new)
+    return FleetArrays(**out)
 
 
 class FactorFleet:
@@ -290,6 +346,8 @@ class FactorFleet:
         self.Kb = 1
         self.f_levels = 1          # bucket-wide static level bounds
         self.b_levels = 1
+        self.f_width = 1           # bucket-wide rows per trisolve sweep
+        self.b_width = 1
         self.generation = 0        # bumped by compact(): row indices moved
         self.compactions = 0
         self.arrays: Optional[FleetArrays] = None
@@ -300,6 +358,16 @@ class FactorFleet:
     @property
     def capacity(self) -> int:
         return 0 if self.arrays is None else int(self.arrays.nvalid.shape[0])
+
+    @property
+    def apply_statics(self) -> Dict:
+        """The static arguments of this fleet's PCG programs
+        (``pcg.fleet_precondition``): apply kind, bucket-wide level
+        bounds and rows per trisolve sweep.  Each distinct value compiles
+        once."""
+        return dict(kind=self.kind, f_levels=self.f_levels,
+                    b_levels=self.b_levels, f_width=self.f_width,
+                    b_width=self.b_width)
 
     @property
     def live_rows(self) -> int:
@@ -339,16 +407,18 @@ class FactorFleet:
             return None if self.device is None else str(self.device)
         return str(next(iter(self.arrays.src.devices())))
 
-    def _row_died(self, ref: weakref.ref) -> None:
+    def _row_died(self, ref: weakref.ref,
+                  _push=heapq.heappush) -> None:
         """Weakref callback: the handle owning ``ref``'s row was
         collected — recycle the row onto the free-heap.  Refs retired by
         a :meth:`compact` are no longer in ``_ref2row`` and fall
-        through harmlessly."""
+        through harmlessly.  ``heappush`` is bound at definition: the
+        callback also fires while the interpreter tears modules down."""
         row = self._ref2row.pop(ref, None)
         if row is not None and row < len(self._rows) \
                 and self._rows[row] is ref:
             self._rows[row] = None
-            heapq.heappush(self._free, row)
+            _push(self._free, row)
 
     def _free_rows(self, k: int) -> List[int]:
         """Claim ``k`` distinct rows: recycled dead rows (ascending —
@@ -389,70 +459,24 @@ class FactorFleet:
         m_pad = max(self.m_pad, *(pf.src.shape[0] for _, pf in pairs))
         Kf = max(self.Kf, *(pf.fwd.K for _, pf in pairs))
         Kb = max(self.Kb, *(pf.bwd.K for _, pf in pairs))
+        f_levels = max(self.f_levels, *(pf.fwd.n_levels for _, pf in pairs))
+        b_levels = max(self.b_levels, *(pf.bwd.n_levels for _, pf in pairs))
         rows = self._free_rows(len(pairs))
         F = max(_next_pow2(max(rows) + 1), self.capacity)
         np_ = self.n_pad
         pf0 = pairs[0][1]
-        with jax.ensure_compile_time_eval():
-            a = self.arrays
-            if a is None:
-                a = FleetArrays(
-                    src=jnp.zeros((F, m_pad), jnp.int32),
-                    dst=jnp.zeros((F, m_pad), jnp.int32),
-                    w=jnp.zeros((F, m_pad), pf0.w.dtype),
-                    fcols=jnp.zeros((F, np_, Kf), jnp.int32),
-                    fvals=jnp.zeros((F, np_, Kf), pf0.fwd.vals.dtype),
-                    flevel=jnp.zeros((F, np_), jnp.int32),
-                    bcols=jnp.zeros((F, np_, Kb), jnp.int32),
-                    bvals=jnp.zeros((F, np_, Kb), pf0.bwd.vals.dtype),
-                    blevel=jnp.zeros((F, np_), jnp.int32),
-                    dinv=jnp.zeros((F, np_), pf0.dinv.dtype),
-                    nvalid=jnp.zeros((F,), jnp.int32),
-                    fnlv=jnp.ones((F,), jnp.int32),
-                    bnlv=jnp.ones((F,), jnp.int32))
-            else:
-                a = FleetArrays(
-                    src=_grow(a.src, (F, m_pad)),
-                    dst=_grow(a.dst, (F, m_pad)),
-                    w=_grow(a.w, (F, m_pad)),
-                    fcols=_grow(a.fcols, (F, np_, Kf)),
-                    fvals=_grow(a.fvals, (F, np_, Kf)),
-                    flevel=_grow(a.flevel, (F, np_)),
-                    bcols=_grow(a.bcols, (F, np_, Kb)),
-                    bvals=_grow(a.bvals, (F, np_, Kb)),
-                    blevel=_grow(a.blevel, (F, np_)),
-                    dinv=_grow(a.dinv, (F, np_)),
-                    nvalid=_grow(a.nvalid, (F,)),
-                    fnlv=jnp.maximum(_grow(a.fnlv, (F,)), 1),
-                    bnlv=jnp.maximum(_grow(a.bnlv, (F,)), 1))
-            ix = jnp.asarray(np.asarray(rows, np.int32))
-            self.arrays = FleetArrays(
-                src=a.src.at[ix].set(jnp.stack(
-                    [_pad1(pf.src, m_pad) for _, pf in pairs])),
-                dst=a.dst.at[ix].set(jnp.stack(
-                    [_pad1(pf.dst, m_pad) for _, pf in pairs])),
-                w=a.w.at[ix].set(jnp.stack(
-                    [_pad1(pf.w, m_pad) for _, pf in pairs])),
-                fcols=a.fcols.at[ix].set(jnp.stack(
-                    [_grow(pf.fwd.cols, (np_, Kf)) for _, pf in pairs])),
-                fvals=a.fvals.at[ix].set(jnp.stack(
-                    [_grow(pf.fwd.vals, (np_, Kf)) for _, pf in pairs])),
-                flevel=a.flevel.at[ix].set(jnp.stack(
-                    [pf.fwd.level_of for _, pf in pairs])),
-                bcols=a.bcols.at[ix].set(jnp.stack(
-                    [_grow(pf.bwd.cols, (np_, Kb)) for _, pf in pairs])),
-                bvals=a.bvals.at[ix].set(jnp.stack(
-                    [_grow(pf.bwd.vals, (np_, Kb)) for _, pf in pairs])),
-                blevel=a.blevel.at[ix].set(jnp.stack(
-                    [pf.bwd.level_of for _, pf in pairs])),
-                dinv=a.dinv.at[ix].set(jnp.stack(
-                    [pf.dinv for _, pf in pairs])),
-                nvalid=a.nvalid.at[ix].set(jnp.asarray(
-                    [pf.n for _, pf in pairs], jnp.int32)),
-                fnlv=a.fnlv.at[ix].set(jnp.asarray(
-                    [pf.fwd.n_levels for _, pf in pairs], jnp.int32)),
-                bnlv=a.bnlv.at[ix].set(jnp.asarray(
-                    [pf.bwd.n_levels for _, pf in pairs], jnp.int32)))
+        shapes = FleetArrays(
+            src=(F, m_pad), dst=(F, m_pad), w=(F, m_pad),
+            fcols=(F, np_, Kf), fvals=(F, np_, Kf), flevel=(F, np_),
+            bcols=(F, np_, Kb), bvals=(F, np_, Kb), blevel=(F, np_),
+            dinv=(F, np_), nvalid=(F,), fnlv=(F,), bnlv=(F,),
+            forder=(F, np_), fext=(F, np_), fgend=(F, np_),
+            fptr=(F, f_levels + 1), border=(F, np_), bext=(F, np_),
+            bgend=(F, np_), bptr=(F, b_levels + 1))
+        self.arrays = _fleet_admit(
+            self.arrays, jnp.asarray(np.asarray(rows, np.int32)),
+            tuple(_row_payload(pf) for _, pf in pairs),
+            shapes=tuple(shapes))
         if self.device is not None:
             # commit the rebuilt stack to the pinned device (no-op copy
             # once resident: growth/scatter of committed arrays already
@@ -461,10 +485,12 @@ class FactorFleet:
             # an adopted factor built on another device lands here.
             self.arrays = jax.device_put(self.arrays, self.device)
         self.m_pad, self.Kf, self.Kb = m_pad, Kf, Kb
-        self.f_levels = max(self.f_levels,
-                            *(pf.fwd.n_levels for _, pf in pairs))
-        self.b_levels = max(self.b_levels,
-                            *(pf.bwd.n_levels for _, pf in pairs))
+        self.f_levels, self.b_levels = f_levels, b_levels
+        if self.kind == "factor":
+            self.f_width = max(self.f_width,
+                               *(pf.fwd.sweep_width for _, pf in pairs))
+            self.b_width = max(self.b_width,
+                               *(pf.bwd.sweep_width for _, pf in pairs))
         for (handle, _), row in zip(pairs, rows):
             ref = weakref.ref(handle, self._row_died)
             self._ref2row[ref] = row
@@ -604,17 +630,14 @@ class PreconditionerHandle:
         or ``(n, nrhs)`` — the fleet apply routed through this handle's
         fleet row (columns become lanes)."""
         fa = self.fleet.arrays
-        fl, bl = self.fleet.f_levels, self.fleet.b_levels
-        kind = self.fleet.kind
+        statics = self.fleet.apply_statics
         n, n_pad = self.n, self.n_pad
         if r.ndim == 1:
             R = jnp.zeros((1, n_pad), r.dtype).at[0, :n].set(r)
-            out = fleet_precondition(fa, self._fidx(1), R,
-                                     f_levels=fl, b_levels=bl, kind=kind)
+            out = fleet_precondition(fa, self._fidx(1), R, **statics)
             return out[0, :n]
         R = jnp.zeros((r.shape[1], n_pad), r.dtype).at[:, :n].set(r.T)
-        out = fleet_precondition(fa, self._fidx(r.shape[1]), R,
-                                 f_levels=fl, b_levels=bl, kind=kind)
+        out = fleet_precondition(fa, self._fidx(r.shape[1]), R, **statics)
         return out[:, :n].T
 
     def solve(self, B, *, tol: float = 1e-6, maxiter: int = 1000,
@@ -629,14 +652,13 @@ class PreconditionerHandle:
             raise ValueError(
                 f"rhs must be (n,) or (nrhs, n) with n={self.n}, "
                 f"got {B.shape}")
-        fl, bl = self.fleet.f_levels, self.fleet.b_levels
-        kind = self.fleet.kind
+        statics = self.fleet.apply_statics
         key = (B.shape, str(B.dtype), float(tol), int(maxiter), project,
-               fl, bl, kind)
+               *sorted(statics.items()))
         fn = self._cache.get(key)
         if fn is None:
             fn = jax.jit(self._build_solve(B.ndim, tol, maxiter, project,
-                                           fl, bl, kind))
+                                           statics))
             self._cache[key] = fn
             while len(self._cache) > self.max_cached_solves:
                 self._cache.popitem(last=False)
@@ -645,8 +667,7 @@ class PreconditionerHandle:
         return fn(B, self.fleet.arrays, jnp.int32(self.fleet_row))
 
     def _build_solve(self, ndim: int, tol: float, maxiter: int,
-                     project: bool, f_levels: int, b_levels: int,
-                     kind: str = "factor"):
+                     project: bool, statics: Dict):
         # the fleet row rides in as a traced argument, not a closure
         # constant: a fleet compaction may move this handle to a new row
         # at any time, and the cached compiled solve must follow it
@@ -660,8 +681,7 @@ class PreconditionerHandle:
                 fa, jnp.full((L,), row, jnp.int32), Bp,
                 jnp.full((L,), tol, jnp.float32),
                 jnp.full((L,), maxiter, jnp.int32),
-                f_levels=f_levels, b_levels=b_levels, kind=kind,
-                project=project)
+                project=project, **statics)
             res = pcg_fleet_result(state, n)
             if ndim == 1:
                 return PCGResult(x=res.x[0], iters=res.iters[0],
@@ -697,7 +717,7 @@ class FactorCache:
     """
 
     def __init__(self, *, chunk: int = 64, fill_slack: int = 32,
-                 strict: bool = True, max_retries: int = 3,
+                 strict: bool = True,
                  dtype=np.float32,
                  memory_budget_bytes: Optional[int] = None,
                  max_handles: Optional[int] = None,
@@ -712,7 +732,6 @@ class FactorCache:
         self.chunk = chunk
         self.fill_slack = fill_slack
         self.strict = strict
-        self.max_retries = max_retries
         self.dtype = dtype
         self.memory_budget_bytes = memory_budget_bytes
         self.max_handles = max_handles
@@ -884,7 +903,7 @@ class FactorCache:
         if family == "ac":
             f = factorize_wavefront(
                 g, key, chunk=self.chunk, fill_slack=self.fill_slack,
-                strict=self.strict, max_retries=self.max_retries,
+                strict=self.strict,
                 dtype=self.dtype, **params)
         else:
             f = fam.build(g, key, dtype=self.dtype, **params)
@@ -920,7 +939,7 @@ class FactorCache:
             fs, scheds = factorize_batched(
                 [gs[i] for i in todo], jnp.stack([keys[i] for i in todo]),
                 chunk=self.chunk, fill_slack=self.fill_slack,
-                strict=self.strict, max_retries=self.max_retries,
+                strict=self.strict,
                 dtype=self.dtype, with_schedules=True)
             admitted = self._attach_many(
                 [(gs[i], f, sch, gids[i], "ac")

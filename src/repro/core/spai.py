@@ -2,8 +2,8 @@
 
 The apply of a SPAI preconditioner is a single SpMV ``z = M r`` with a
 *materialized* sparse approximate inverse ``M ≈ L⁺`` — which makes it a
-perfect fit for the fleet's lane-batched ELL SpMV kernel
-(``repro.kernels.spmv.ell_spmv_fleet_pallas``): one kernel launch per
+perfect fit for the fleet's lane-batched ELL SpMV
+(``repro.kernels.ops.ell_spmv_fleet``): one SpMV per
 PCG iteration instead of the ``f_levels + b_levels`` masked sweeps a
 triangular factor pays.  This is the serving-side point of the SPAI
 lineage (arxiv 2510.27517): trade construction-time least squares for a
@@ -69,39 +69,51 @@ class EllPrecond:
                       axis=1)
 
 
-def dense_to_ell(M: np.ndarray, *, droptol: float = 0.0,
-                 dtype=np.float32) -> EllPrecond:
-    """Pack a dense symmetric approximate inverse into ELL rows.
+def matrix_to_ell(M, *, droptol: float = 0.0,
+                  dtype=np.float32) -> EllPrecond:
+    """Pack a symmetric approximate inverse (dense array or scipy sparse
+    matrix) into ELL rows.
 
     Entries with ``|m_ij| < droptol · max|M|`` are dropped (a global
     threshold keeps the drop mask symmetric, so the packed operator
     stays symmetric); diagonal entries are always kept.  ``K`` is the
-    post-drop maximum row count.
+    post-drop maximum row count.  A sparse ``M`` is packed without ever
+    densifying it, so the cost is O(nnz), not O(n²).
 
     Args:
-        M: dense ``(n, n)`` symmetric operator.
+        M: ``(n, n)`` symmetric operator, dense or sparse.
         droptol: relative drop threshold (``0.0`` keeps everything).
         dtype: value dtype of the packed rows.
 
     Returns:
         The packed :class:`EllPrecond`.
     """
+    M = sp.csr_matrix(M)
+    M.sum_duplicates()
     n = M.shape[0]
+    rows = np.repeat(np.arange(n), np.diff(M.indptr))
+    mag = np.abs(M.data)
     if droptol > 0.0:
-        mmax = float(np.abs(M).max())
-        keep = np.abs(M) >= droptol * (mmax if mmax > 0.0 else 1.0)
+        mmax = float(mag.max()) if mag.size else 0.0
+        keep = mag >= droptol * (mmax if mmax > 0.0 else 1.0)
     else:
-        keep = np.abs(M) != 0.0
-    np.fill_diagonal(keep, True)
-    counts = keep.sum(axis=1)
-    K = max(int(counts.max()), 1)
+        keep = mag != 0.0
+    keep |= rows == M.indices
+    r, c, v = rows[keep], M.indices[keep], M.data[keep]
+    missing = np.setdiff1d(np.arange(n), r[r == c])
+    r = np.concatenate([r, missing])
+    c = np.concatenate([c, missing])
+    v = np.concatenate([v, np.zeros(missing.size, v.dtype)])
+    order = np.lexsort((c, r))
+    r, c, v = r[order], c[order], v[order]
+    counts = np.bincount(r, minlength=n)
+    K = max(int(counts.max()), 1) if n else 1
+    slot = np.arange(r.size) - np.repeat(np.cumsum(counts) - counts, counts)
     cols = np.zeros((n, K), np.int32)
     vals = np.zeros((n, K), dtype)
-    for i in range(n):
-        js = np.nonzero(keep[i])[0]
-        cols[i, :js.size] = js
-        vals[i, :js.size] = M[i, js].astype(dtype)
-    return EllPrecond(n=n, cols=cols, vals=vals, nnz=int(counts.sum()),
+    cols[r, slot] = c
+    vals[r, slot] = v.astype(dtype)
+    return EllPrecond(n=n, cols=cols, vals=vals, nnz=int(r.size),
                       meta={"droptol": float(droptol)})
 
 
@@ -173,7 +185,6 @@ def spai_ell_precond(g: Graph, *, droptol: float = 0.0,
         ``meta`` (``{"family": "spai", "nnz_G": ...}``).
     """
     G = fsai_lower(g)
-    M = (G.T @ G).toarray()
-    out = dense_to_ell(M, droptol=droptol, dtype=dtype)
+    out = matrix_to_ell(G.T @ G, droptol=droptol, dtype=dtype)
     out.meta.update(family="spai", nnz_G=int(G.nnz))
     return out

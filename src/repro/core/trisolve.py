@@ -163,12 +163,11 @@ def _propagate_levels(dst, src, *, n: int):
     """Longest-path levels by iterative relaxation under ``while_loop`` —
     converges in (#levels) passes, all on device.
 
-    Deliberately NOT ``@jax.jit``-wrapped: it always runs on concrete
-    arrays under ``ensure_compile_time_eval`` (schedule construction is
-    compile-time work), and jax 0.4.x mis-tracks inner-jit argument
-    tracers in that nesting (jit → ensure_compile_time_eval → jit with a
-    ``while_loop``), raising ``UnexpectedTracerError``.  Eager dispatch
-    costs one primitive per line, once per factor."""
+    Not ``@jax.jit``-wrapped: it always runs on concrete arrays under
+    ``ensure_compile_time_eval`` (schedule construction is compile-time
+    work, also when ``make_preconditioner`` is called inside an outer
+    ``jit``), so eager dispatch costs one primitive per line, once per
+    factor."""
     def cond(c):
         return c[1]
 
@@ -279,22 +278,101 @@ class PackedSchedule:
     cols: jnp.ndarray       # int32[n_pad, K]
     vals: jnp.ndarray       # f32[n_pad, K]
     level_of: jnp.ndarray   # int32[n_pad] (0 for phantom rows)
+    # the sweep plan (``kernels.ops.sweep_plan``): rows by level, then by
+    # descending panel extent; each row's extent in that order; where
+    # each run of one level and panel class ends; where each level
+    # starts
+    order: jnp.ndarray      # int32[n_pad]
+    extent: jnp.ndarray     # int32[n_pad]
+    group_end: jnp.ndarray  # int32[n_pad]
+    level_ptr: jnp.ndarray  # int32[n_levels + 1]
 
     @property
     def device_bytes(self) -> int:
         return int(self.cols.nbytes + self.vals.nbytes
-                   + self.level_of.nbytes)
+                   + self.level_of.nbytes + self.order.nbytes
+                   + self.extent.nbytes + self.group_end.nbytes
+                   + self.level_ptr.nbytes)
+
+    @property
+    def level_width(self) -> int:
+        """Rows in the widest level ≥ 1 (level-0 rows have no in-edges
+        and are never swept), the cap on :attr:`sweep_width`.  At
+        least 1."""
+        if self.n_levels <= 1:
+            return 1
+        counts = np.bincount(np.asarray(self.level_of),
+                             minlength=self.n_levels)
+        return max(int(counts[1:].max()), 1)
+
+    @property
+    def sweep_width(self) -> int:
+        """Rows per trisolve sweep (``kernels.ops.trisolve_fleet``'s
+        ``width``): the power of two at or above the mean row count of
+        the levels ≥ 1, capped at the widest.  A solve then takes at
+        most about twice as many sweeps as it has levels, and sweeps
+        about twice as many rows as it has."""
+        if self.n_levels <= 1:
+            return 1
+        ptr = np.asarray(self.level_ptr)
+        mean = -(-int(ptr[-1] - ptr[1]) // (self.n_levels - 1))
+        return max(min(_next_pow2(mean), self.level_width), 1)
 
 
+def sweep_plan_np(level_of: np.ndarray, extent: np.ndarray,
+                  n_levels: int):
+    """Host form of ``kernels.ops.sweep_plan`` from per-row levels and
+    panel extents (row-indexed): ``(order, extent in order, group_end,
+    level_ptr)`` as int32 arrays."""
+    n = level_of.shape[0]
+    order = np.lexsort((-extent.astype(np.int64), level_of))
+    ext, lv = extent[order], level_of[order]
+    cls = np.maximum(np.left_shift(
+        1, np.ceil(np.log2(np.maximum(ext, 1))).astype(np.int64)), 8)
+    last = np.ones(n, bool)
+    last[:-1] = (lv[1:] != lv[:-1]) | (cls[1:] != cls[:-1])
+    ends = np.flatnonzero(last) + 1
+    group_end = np.repeat(ends, np.diff(np.concatenate([[0], ends])))
+    ptr = np.searchsorted(lv, np.arange(n_levels + 1), side="left")
+    return (order.astype(np.int32), ext.astype(np.int32),
+            group_end.astype(np.int32), ptr.astype(np.int32))
+
+
+@partial(jax.jit, static_argnames=("n",))
 def _propagate_levels_fleet(dst, src, *, n: int):
     """``_propagate_levels`` vmapped over a padded fleet: ``dst``/``src``
     are ``(B, E)`` with invalid (padding) edges marked ``dst == n`` so
     their relaxation drops.  One batched ``while_loop`` runs until every
     member converges — the whole fleet's level propagation is a single
-    XLA program instead of B sequential ones."""
-    return jax.vmap(partial(_propagate_levels, n=n))(dst, src)
+    XLA program instead of B sequential ones.  Also returns each row's
+    in-degree (its panel extent)."""
+    levels = jax.vmap(partial(_propagate_levels, n=n))(dst, src)
+    indeg = jax.vmap(lambda d: jnp.zeros(n, jnp.int32).at[d].add(
+        1, mode="drop"))(dst)
+    return levels, indeg
 
 
+@partial(jax.jit, static_argnames=("ns", "nnzs", "n_bat", "E_bat"))
+def _fleet_solve_edges(col_ptrs, rows, vals, *, ns, nnzs, n_bat: int,
+                       E_bat: int):
+    """The ``(2B, E_bat)`` solve-edge batch of ``build_schedules_batched``
+    from B device factors' CSC arrays: forward edges (CSC entry i ∈ col
+    k ⇒ dst=i, src=k) in the first B rows, backward (dst=k, src=i) in
+    the last B; padding edges have ``dst == n_bat``."""
+    DST, SRC, VAL = [], [], []
+    for col_ptr, r, v, n, nnz in zip(col_ptrs, rows, vals, ns, nnzs):
+        cols_of = jnp.repeat(jnp.arange(n, dtype=jnp.int32),
+                             jnp.diff(col_ptr), total_repeat_length=nnz)
+        DST.append(_pad_dev(r.astype(jnp.int32), E_bat, n_bat))
+        SRC.append(_pad_dev(cols_of, E_bat, 0))
+        VAL.append(_pad_dev(v, E_bat, 0))
+    for b in range(len(ns)):
+        DST.append(jnp.where(DST[b] < n_bat, SRC[b], n_bat))
+        SRC.append(jnp.where(DST[b] < n_bat, DST[b], 0))
+    return jnp.stack(DST), jnp.stack(SRC), jnp.stack(VAL + VAL)
+
+
+@partial(jax.jit, static_argnames=("n", "K"))
 def _pack_row_panels_fleet(dst, src, val, *, n: int, K: int):
     """Row-indexed ELL packing, vmapped: edge ``e`` lands in slot
     ``(dst_e, rank_e)`` where rank is the edge's position within its
@@ -319,6 +397,14 @@ def _pad_dev(x, size, fill):
     return jnp.concatenate(
         [x, jnp.full((size - x.shape[0],), fill, x.dtype)]) \
         if x.shape[0] != size else x
+
+
+@partial(jax.jit, static_argnames=("row", "n_pad", "K"))
+def _half_panels(cols, vals, levels, row: int, *, n_pad: int, K: int):
+    """One solve's panels and levels, sliced out of the fleet batch to
+    its own padded shape."""
+    return (cols[row, :n_pad, :K], vals[row, :n_pad, :K],
+            levels[row, :n_pad])
 
 
 def build_schedules_batched(
@@ -351,38 +437,20 @@ def build_schedules_batched(
     nnzs = [d.nnz for d in devs]
     n_bat = _next_pow2(max(ns))
     E_bat = max(_next_pow2(max(nnzs)), 1)
-    # all inputs are concrete device buffers (DeviceFactor's contract),
-    # so everything below dispatches eagerly — deliberately NOT wrapped
-    # in ensure_compile_time_eval: jax 0.4.x mis-tracks vmap-of-while
-    # tracers under that context (UnexpectedTracerError).
-    DST, SRC, VAL = [], [], []
-    for d in devs:
-        counts = jnp.diff(d.col_ptr)
-        cols_of = jnp.repeat(jnp.arange(d.n, dtype=jnp.int32), counts,
-                             total_repeat_length=d.nnz)
-        rows = d.rows.astype(jnp.int32)
-        vals = d.vals
-        # forward then (later) backward rows share the padded vals
-        DST.append(_pad_dev(rows, E_bat, n_bat))
-        SRC.append(_pad_dev(cols_of, E_bat, 0))
-        VAL.append(_pad_dev(vals, E_bat, 0))
-    # second half of the batch: backward solve edges (dst=k, src=i)
-    for b in range(B):
-        DST.append(jnp.where(DST[b] < n_bat, SRC[b], n_bat))
-        SRC.append(jnp.where(DST[b] < n_bat, DST[b], 0))
-    VAL = VAL + VAL
-    DSTa = jnp.stack(DST)
-    SRCa = jnp.stack(SRC)
-    VALa = jnp.stack(VAL)
-    levels = _propagate_levels_fleet(DSTa, SRCa, n=n_bat)
-    indeg = jax.vmap(
-        lambda d: jnp.zeros(n_bat, jnp.int32).at[d].add(
-            1, mode="drop"))(DSTa)
+    # all inputs are concrete device buffers (DeviceFactor's contract);
+    # the derivation runs as a few jitted programs (eager dispatch would
+    # compile every primitive separately for each new shape)
+    DSTa, SRCa, VALa = _fleet_solve_edges(
+        tuple(d.col_ptr for d in devs), tuple(d.rows for d in devs),
+        tuple(d.vals for d in devs), ns=tuple(ns), nnzs=tuple(nnzs),
+        n_bat=n_bat, E_bat=E_bat)
+    levels, indeg = _propagate_levels_fleet(DSTa, SRCa, n=n_bat)
     K_bat = max(_next_pow2(int(indeg.max())), 1)
     COLS, VALS = _pack_row_panels_fleet(DSTa, SRCa, VALa,
                                         n=n_bat, K=K_bat)
     levels_h = np.asarray(levels)
-    kmax_h = np.asarray(indeg.max(axis=1))
+    indeg_h = np.asarray(indeg)
+    kmax_h = indeg_h.max(axis=1)
 
     out: List[Tuple[PackedSchedule, PackedSchedule]] = []
     for b in range(B):
@@ -391,13 +459,17 @@ def build_schedules_batched(
             n = ns[b]
             n_pad = _next_pow2(n)
             K = max(_next_pow2(int(kmax_h[row])), 1)
-            cols = jax.lax.slice(COLS[row], (0, 0), (n_pad, K))
-            vals = jax.lax.slice(VALS[row], (0, 0), (n_pad, K))
-            lvl = jax.lax.slice(levels[row], (0,), (n_pad,))
+            cols, vals, lvl = _half_panels(COLS, VALS, levels, row,
+                                           n_pad=n_pad, K=K)
+            n_levels = int(levels_h[row, :n].max(initial=0)) + 1
+            order, extent, group_end, ptr = sweep_plan_np(
+                levels_h[row, :n_pad], indeg_h[row, :n_pad], n_levels)
             halves.append(PackedSchedule(
-                n=n, n_pad=n_pad,
-                n_levels=int(levels_h[row, :n].max(initial=0)) + 1,
-                K=K, cols=cols, vals=vals, level_of=lvl))
+                n=n, n_pad=n_pad, n_levels=n_levels, K=K, cols=cols,
+                vals=vals, level_of=lvl, order=jnp.asarray(order),
+                extent=jnp.asarray(extent),
+                group_end=jnp.asarray(group_end),
+                level_ptr=jnp.asarray(ptr)))
         out.append((halves[0], halves[1]))
     return out
 

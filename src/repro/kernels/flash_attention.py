@@ -40,10 +40,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block_k, causal,
 
     def body(kb, carry):
         m_prev, l_prev, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kb * block_k, block_k),
-                            slice(None)))          # (Tk, d)
-        v = pl.load(v_ref, (pl.dslice(kb * block_k, block_k),
-                            slice(None)))
+        k = k_ref[pl.ds(kb * block_k, block_k), :]    # (Tk, d)
+        v = v_ref[pl.ds(kb * block_k, block_k), :]
         s = jax.lax.dot_general(
             q.astype(jnp.float32) * scale, k.astype(jnp.float32),
             (((1,), (1,)), ((), ())))              # (Tq, Tk)

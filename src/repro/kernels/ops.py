@@ -1,8 +1,13 @@
-"""Jit'd public wrappers around the Pallas kernels: padding, layout
-conversion, and level-scheduled triangular solve built on the SpMV
-kernel.  ``interpret=None`` everywhere: the mode is resolved per
-process by :mod:`repro.kernels.runtime` (``REPRO_PALLAS_INTERPRET``
-env override, else interpret on CPU and native on GPU/TPU backends).
+"""Jit'd public wrappers around the kernels: padding, layout
+conversion, and level-scheduled triangular solve built on the SpMV.
+
+The served path's lane-batched SpMV (``ell_spmv_fleet``, and the masked
+sweeps of ``trisolve_fleet`` built on it) is plain XLA on every backend.
+The Pallas wrappers (``sample_clique``, ``ell_spmv``, ``ell_spmv_multi``
+and the single-factor solves on them) take ``interpret=None``: the mode
+is resolved per process by :mod:`repro.kernels.runtime`
+(``REPRO_PALLAS_INTERPRET`` env override, else interpret on CPU and
+native on GPU/TPU backends).
 """
 from __future__ import annotations
 
@@ -15,8 +20,7 @@ import jax.numpy as jnp
 
 from .sample_clique import sample_clique_pallas, INVALID_ID
 from .runtime import resolve_interpret
-from .spmv import (ell_spmv_pallas, ell_spmv_multi_pallas,
-                   ell_spmv_fleet_pallas)
+from .spmv import ell_spmv_pallas, ell_spmv_multi_pallas
 from . import ref as kref
 
 
@@ -121,10 +125,34 @@ def trisolve_levels(level_rows, level_cols, level_vals, b, flip: bool = False,
     return y[::-1] if flip else y
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def ell_spmv_fleet(cols, vals, x, *, interpret: Optional[bool] = None):
-    """Lane-batched ELL SpMV; cols/vals: [L, R, K], x: [L, n] → [L, R]."""
-    return ell_spmv_fleet_pallas(cols, vals, x, interpret=interpret)
+@jax.jit
+def ell_spmv_fleet(cols, vals, x):
+    """Lane-batched ELL SpMV; cols/vals: [L, R, K], x: [L, n] → [L, R].
+
+    An XLA gather-multiply-reduce on every backend: ``Y[l, i] =
+    Σ_k vals[l,i,k] · x[l, cols[l,i,k]]``.  The served path (every fleet
+    PCG apply and masked sweep) runs this form, so CPU tests exercise
+    the program the TPU runs.  ``ell_spmv_fleet_pallas`` computes the
+    same product as a Pallas kernel; the TPU compiler refuses its
+    random gather out of VMEM, so it is not on the served path."""
+    L, R, K = cols.shape
+    xg = jnp.take_along_axis(x, cols.reshape(L, R * K), axis=1)
+    return _pairwise_sum(vals * xg.reshape(L, R, K))
+
+
+def _pairwise_sum(p):
+    """Sum over the last axis in a fixed pairwise order.  Elementwise
+    adds cannot be reassociated by the compiler, so a row's sum does not
+    depend on how many lanes or rows share the array — what keeps a
+    served lane bit-identical to a direct solve (``jnp.sum``'s order is
+    the backend's choice and changes with the batch shape)."""
+    while p.shape[-1] > 1:
+        k = p.shape[-1]
+        h = k // 2
+        head = p[..., :h] + p[..., h:2 * h]
+        p = head if k % 2 == 0 else jnp.concatenate([head, p[..., 2 * h:]],
+                                                    axis=-1)
+    return p[..., 0]
 
 
 def trisolve_masked(cols, vals, level_of, y, *, n_levels: int,
@@ -149,40 +177,167 @@ def trisolve_masked(cols, vals, level_of, y, *, n_levels: int,
     return jax.lax.fori_loop(1, n_levels, body, y)
 
 
+def panel_class(extent):
+    """A row's panel class: the power of two at or above its panel
+    extent, at least 8.  Rows of one class are swept together on that
+    many panel slots (``trisolve_fleet``)."""
+    e = jnp.maximum(jnp.asarray(extent, jnp.int32), 1)
+    return jnp.maximum(jnp.left_shift(1, 32 - jax.lax.clz(e - 1)), 8)
+
+
+def sweep_plan(vals, level_of, n_levels: int):
+    """The row order a fleet trisolve sweeps, computed from panels.
+
+    For lanes of row-indexed panels ``vals`` ``(L, n, K)`` and levels
+    ``level_of`` ``(L, n)`` returns ``(order, extent, group_end,
+    level_ptr)``: ``order`` lists each lane's rows by ascending level,
+    within a level by descending panel extent (one past the row's last
+    nonzero slot — slots beyond it contribute exactly zero), then by row
+    id; ``extent[l, j]`` is the extent of row ``order[l, j]``;
+    ``group_end[l, j]`` is where the run of rows sharing position
+    ``j``'s level and :func:`panel_class` ends; ``level_ptr[l, v]`` is
+    where level ``v`` starts (``n`` past the last level).  Schedules
+    built for a fleet carry the same arrays precomputed
+    (``trisolve.PackedSchedule``); this in-program form serves callers
+    that hold bare panels."""
+    L, n, K = vals.shape
+    slot = jnp.arange(1, K + 1, dtype=jnp.int32)
+    ext = jnp.max(jnp.where(vals != 0, slot, 0), axis=2)          # (L, n)
+    key = level_of.astype(jnp.int32) * (K + 1) + (K - ext)
+    order = jnp.argsort(key, axis=1, stable=True).astype(jnp.int32)
+    extent = jnp.take_along_axis(ext, order, axis=1)
+    lv = jnp.take_along_axis(level_of.astype(jnp.int32), order, axis=1)
+    cls = panel_class(extent)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    last = jnp.concatenate(
+        [(lv[:, 1:] != lv[:, :-1]) | (cls[:, 1:] != cls[:, :-1]),
+         jnp.ones((L, 1), bool)], axis=1)                  # run ends at j
+    group_end = jax.lax.associative_scan(
+        jnp.minimum, jnp.where(last, pos + 1, n), axis=1, reverse=True)
+    level_ptr = jax.vmap(lambda s: jnp.searchsorted(
+        s, jnp.arange(n_levels + 1, dtype=s.dtype), side="left"))(lv)
+    return order, extent, group_end.astype(jnp.int32), \
+        level_ptr.astype(jnp.int32)
+
+
+def _k_classes(K: int):
+    """The panel classes a sweep of ``K``-slot panels runs: powers of
+    two from 8 up to ``K`` when ``K`` is a power of two above 8, else
+    ``K`` alone."""
+    if K <= 8 or K & (K - 1):
+        return (K,)
+    classes, k = [], 8
+    while k <= K:
+        classes.append(k)
+        k *= 2
+    return tuple(classes)
+
+
 def trisolve_fleet(cols, vals, level_of, y, *, n_levels: int,
-                   interpret: Optional[bool] = None, lane_levels=None):
-    """Lane-batched ``trisolve_masked``: cols/vals ``(L, n, K)``,
-    ``level_of`` ``(L, n)``, ``y`` ``(L, n)`` — each lane solves against
-    its own panels (gathered from a stacked factor fleet by the caller).
+                   lane_levels=None, width=None, plan=None, fidx=None):
+    """Lane-batched level-scheduled unit-triangular solve: cols/vals
+    ``(L, n, K)`` row-indexed panels, ``level_of`` ``(L, n)``, ``y``
+    ``(L, n)`` — each lane solves against its own panels.  With
+    ``fidx`` (``(L,)`` int32) ``cols``/``vals`` are instead a factor
+    fleet's ``(F, n, K)`` stacks and lane ``l`` reads row ``fidx[l]``:
+    sweeps gather their rows straight out of the stack, and no lane
+    copy of the panels is made.
 
-    ``n_levels`` is the static bucket-wide ceiling.  ``lane_levels``
-    (optional, ``(L,)`` int32, traced) carries each lane's *true* level
-    count: when given, the loop runs a ``while_loop`` bounded by the
-    batch's live maximum instead of a ``fori_loop`` to the ceiling, so
-    sweeps past every live lane's depth are never launched.  Bit-exact
-    either way: a level ``lv >= lane_levels[l]`` selects no rows of lane
-    ``l`` (``level_of`` never reaches it), so skipping it only removes
-    no-op sweeps."""
-    def sweep(lv, y):
-        contrib = ell_spmv_fleet(cols, vals, y, interpret=interpret)
-        return jnp.where(level_of == lv, y - contrib, y)
+    Each lane walks its rows in :func:`sweep_plan` order, in sweeps
+    that never cross a run of one level and one :func:`panel_class`: a
+    level takes as many sweeps as its runs need, and each sweep gathers
+    only its class's leading panel slots.  A sweep of the narrowest
+    class takes at most ``width`` rows (default ``n``), and a class of
+    ``k`` slots proportionally fewer (``width × narrowest / k``, at
+    least one), so every sweep gathers about as many slots.  Lanes
+    advance on their own; a sweep runs the widest class any live lane
+    is at, and lanes at a narrower class wait for it.  So a row's update
+    ``y - Σ_k vals·y[cols]`` is computed by the same arithmetic, on the
+    same slots, whatever the sweep width or lane mix — these change the
+    work (a few sweeps per level, instead of one sweep of ``n × K``
+    slots), never a result.
 
-    if lane_levels is None:
-        return jax.lax.fori_loop(1, n_levels, sweep, y)
+    ``plan`` is ``sweep_plan``'s ``(order, extent, group_end,
+    level_ptr)`` with ``level_ptr`` ``n_levels + 1`` wide.  With a plan,
+    the panels are stored in plan order — panel row ``j`` of a lane
+    holds the in-edges of row ``order[j]``, as a fleet stores them — so
+    a sweep reads one contiguous block of panel rows (a gather of rows
+    by index is a loop of single-row copies on a TPU).  Without one, the
+    plan is computed here and the row-indexed panels are put into plan
+    order first.  ``n_levels`` is the static bucket-wide ceiling;
+    ``lane_levels`` (optional, ``(L,)`` int32, traced) carries each
+    lane's *true* level count, and a lane stops there (a lane given 1
+    does nothing) — levels past it select no rows, so stopping early
+    only removes no-op sweeps."""
+    L, n = y.shape
+    K = cols.shape[2]
+    C = n if width is None else max(1, min(int(width), n))
+    lane_of = jnp.arange(L) if fidx is None else fidx
+    if plan is None:
+        if fidx is not None:
+            cols, vals, lane_of = cols[fidx], vals[fidx], jnp.arange(L)
+        plan = sweep_plan(vals, level_of, n_levels)
+        cols = jnp.take_along_axis(cols, plan[0][:, :, None], axis=1)
+        vals = jnp.take_along_axis(vals, plan[0][:, :, None], axis=1)
+    order, extent, group_end, level_ptr = plan
+    classes = _k_classes(K)
+    first = jnp.concatenate(
+        [panel_class(extent), jnp.zeros((L, 1), jnp.int32)], axis=1)
+    group_end = jnp.concatenate(
+        [group_end.astype(jnp.int32), jnp.full((L, 1), n, jnp.int32)],
+        axis=1)
+    bound = jnp.full((L,), n_levels, jnp.int32) if lane_levels is None \
+        else jnp.clip(lane_levels.astype(jnp.int32), 1, n_levels)
+    stop = jnp.take_along_axis(level_ptr.astype(jnp.int32),
+                               bound[:, None], axis=1)[:, 0]
+    lanes = jnp.arange(L)[:, None]
+    cut = jnp.asarray(classes[:-1], jnp.int32)
 
-    bound = jnp.minimum(jnp.max(lane_levels).astype(jnp.int32),
-                        jnp.int32(n_levels))
+    def branch(kc):
+        # rows × slots per sweep stay at about width × the narrowest class
+        rows_k = max(1, C * classes[0] // kc)
+
+        def run(start, go, gend, y):
+            end = jnp.where(go, jnp.minimum(start + rows_k, gend), start)
+            # the sweep's rows are one contiguous block of the plan; near
+            # the end the block starts early and its head is masked
+            at = jnp.minimum(start, n - rows_k)
+            pos = at[:, None] + jnp.arange(rows_k, dtype=jnp.int32)
+            take = (pos >= start[:, None]) & (pos < end[:, None])
+            rows = jax.vmap(lambda o, a: jax.lax.dynamic_slice(
+                o, (a,), (rows_k,)))(order, at)
+            rows = jnp.where(take, rows, n)
+
+            c = jax.vmap(lambda f, a: jax.lax.dynamic_slice(
+                cols, (f, a, 0), (1, rows_k, kc))[0])(lane_of, at)
+            v = jax.vmap(lambda f, a: jax.lax.dynamic_slice(
+                vals, (f, a, 0), (1, rows_k, kc))[0])(lane_of, at)
+            upd = jnp.take_along_axis(y, jnp.minimum(rows, n - 1), axis=1) \
+                - ell_spmv_fleet(c, v, y)
+            return end, y.at[lanes, rows].set(upd, mode="drop")  # pads drop
+        return run
+
+    branches = [branch(kc) for kc in classes]
 
     def cond(carry):
-        lv, _ = carry
-        return lv < bound
+        start, _ = carry
+        return jnp.any(start < stop)
 
     def body(carry):
-        lv, y = carry
-        return lv + jnp.int32(1), sweep(lv, y)
+        start, y = carry
+        live = start < stop
+        at = jnp.minimum(start, n)[:, None]
+        cls = jnp.sum(jnp.take_along_axis(first, at, axis=1)
+                      > cut[None, :], axis=1)                     # (L,)
+        run_cls = jnp.max(jnp.where(live, cls, 0))
+        go = live & (cls == run_cls)
+        gend = jnp.take_along_axis(group_end, at, axis=1)[:, 0]
+        if len(branches) == 1:
+            return branches[0](start, go, gend, y)
+        return jax.lax.switch(run_cls, branches, start, go, gend, y)
 
-    _, y = jax.lax.while_loop(cond, body, (jnp.int32(1), y))
-    return y
+    start0 = level_ptr[:, 1].astype(jnp.int32)
+    return jax.lax.while_loop(cond, body, (start0, y))[1]
 
 
 def trisolve_panels(sched, b, flip: bool = False,

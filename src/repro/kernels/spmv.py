@@ -1,21 +1,19 @@
-"""Pallas ELL-format SpMV — the PCG solve-phase hot loop (paper §6:
-both the randomized factor application and CG's matvec are
-bandwidth-bound; ELL padding makes the access pattern rectangular, the
-TPU-friendly replacement for cuSPARSE's CSR vector kernels).
+"""Pallas ELL-format SpMV kernels (paper §6: both the randomized factor
+application and CG's matvec are bandwidth-bound; ELL padding makes the
+access pattern rectangular).
 
-Layout: rows padded to a fixed ``K`` nonzeros (ELLPACK).  The dense
-vector x lives wholly in VMEM (fits for n ≤ ~2M fp32 — the laptop-scale
-regime; beyond that rows are bucketed into column-sliced panels, same
-kernel per panel).  Each grid step processes a (Rb, K) row tile:
-gather x at the tile's column indices, multiply by the tile's values,
-reduce along K.
+Layout: rows padded to a fixed ``K`` nonzeros (ELLPACK).  Each grid step
+processes a ``(Rb, K)`` row tile: gather x at the tile's column
+indices, multiply by the tile's values, reduce along K.  The x block
+spans the whole vector, and the gather is a general random gather out
+of it.
 
-The same kernel executes the *level-scheduled triangular solve* step:
-``y_level = b_level − ELL_rows_level @ y`` (ops.trisolve_levels), which
-is how the paper's critical-path analysis (Fig. 4) maps onto TPU.
-
-Validated in interpret mode; on real TPU the x-gather lowers via
-dynamic-slice loops (small K) — noted in DESIGN.md §7.
+Only interpret mode runs these kernels: the TPU compiler refuses the
+random gather (``Only 2D gather is supported``), and the fleet kernel's
+``(1, n)`` block breaks its (8, 128) tiling rule.  The served path
+therefore uses the XLA form ``repro.kernels.ops.ell_spmv_fleet``, which
+computes the same product; ``tests/test_tpu_compile.py`` compiles it
+for a v5e.
 """
 from __future__ import annotations
 

@@ -21,6 +21,8 @@ import argparse
 import json
 import time
 
+from repro.launch import compile_cache
+
 
 def build_cluster(*, suite="tiny", replicas=2, routing="affinity",
                   slots=8, iters_per_tick=8, chunk=128, fill_slack=32,
@@ -334,6 +336,7 @@ def main():
                          "SLO-miss streak) dumps the recent event ring "
                          "plus a stats/metrics sample to JSONL here")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.obs import (MetricsRegistry, SustainedThresholdDetector,
                            Tracer, maybe_serve)

@@ -10,20 +10,11 @@ from __future__ import annotations
 import jax
 
 
-def mesh_axis_types(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh`` when this jax exposes
-    the explicit-sharding ``AxisType`` API; older builds type axes Auto
-    implicitly, so the kwarg is simply omitted."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -31,4 +22,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **mesh_axis_types(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

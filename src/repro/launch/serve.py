@@ -39,6 +39,7 @@ import json
 import time
 
 
+from repro.launch import compile_cache
 from repro.obs.histogram import percentile
 
 # suite names resolved against the canonical registry in repro.data.graphs
@@ -452,6 +453,7 @@ def main():
                          "(driver crash, SLO-miss streak) dumps the last "
                          "events + a metrics sample to JSONL files here")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from repro.obs import MetricsRegistry, Tracer, maybe_serve
     registry = MetricsRegistry() \

@@ -16,6 +16,8 @@ import time
 
 import numpy as np
 
+from repro.launch import compile_cache
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -30,6 +32,7 @@ def main():
                     help="solve N right-hand sides in one batched PCG "
                          "sharing the factor")
     args = ap.parse_args()
+    compile_cache.enable()
 
     import jax
     import jax.numpy as jnp

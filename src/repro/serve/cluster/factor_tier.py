@@ -119,8 +119,7 @@ class FactorReplica(threading.Thread):
             fs, scheds = factorize_batched(
                 [j.g for j in batch], jnp.stack([j.key for j in batch]),
                 chunk=t.chunk, fill_slack=t.fill_slack, strict=t.strict,
-                max_retries=t.max_retries, dtype=t.dtype,
-                with_schedules=True, device=self.device)
+                dtype=t.dtype, with_schedules=True, device=self.device)
             return list(zip(fs, scheds))
         job = batch[0]
         fam = get_family(job.family)
@@ -129,7 +128,6 @@ class FactorReplica(threading.Thread):
             kw.setdefault("chunk", t.chunk)
             kw.setdefault("fill_slack", t.fill_slack)
             kw.setdefault("strict", t.strict)
-            kw.setdefault("max_retries", t.max_retries)
         if self.device is not None:
             with jax.default_device(self.device):
                 f = fam.build(job.g, job.key, dtype=t.dtype, **kw)
@@ -245,7 +243,7 @@ class FactorTier:
         replicas: worker-thread count.
         devices: per-worker device pinning (``None`` entries leave the
             worker on the process default device).
-        chunk / fill_slack / strict / max_retries / dtype: construction
+        chunk / fill_slack / strict / dtype: construction
             parameters — must match the serving caches' so adopted
             factors are bit-identical to colocated ones.
         max_batch: coalescing cap per ``factorize_batched`` call.
@@ -260,7 +258,7 @@ class FactorTier:
     def __init__(self, replicas: int = 1, *,
                  devices: Optional[Sequence[Optional[jax.Device]]] = None,
                  chunk: int = 64, fill_slack: int = 32,
-                 strict: bool = True, max_retries: int = 3,
+                 strict: bool = True,
                  dtype=np.float32, max_batch: int = 16,
                  max_failovers: int = 8,
                  on_retarget: Optional[Callable] = None,
@@ -270,7 +268,6 @@ class FactorTier:
         self.chunk = chunk
         self.fill_slack = fill_slack
         self.strict = strict
-        self.max_retries = max_retries
         self.dtype = dtype
         self.max_batch = max_batch
         self.max_failovers = max_failovers

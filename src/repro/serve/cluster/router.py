@@ -561,7 +561,6 @@ class SolveCluster:
             chunk=ckw.get("chunk", 64),
             fill_slack=ckw.get("fill_slack", 32),
             strict=ckw.get("strict", True),
-            max_retries=ckw.get("max_retries", 3),
             dtype=ckw.get("dtype", np.float32),
             max_batch=factor_max_batch,
             on_retarget=self._retarget,

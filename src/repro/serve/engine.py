@@ -203,9 +203,9 @@ class EngineStats:
     # -- padding-tax accounting ---------------------------------------------
     # sweeps_skipped: trisolve level sweeps the dynamic per-lane bounds
     # elided vs the static bucket ceilings (summed over stepped buckets);
-    # sweep_elements: padded (lanes × n_pad × K × live sweeps) panel
-    # elements swept per apply, the K-tiering figure of merit gated by
-    # check_serve_regression; fleet_resyncs: bucket fidx re-scatters
+    # sweep_elements: padded (lanes × sweep rows × K × sweeps) panel
+    # elements swept per apply (modelled, see _account_sweeps), the
+    # K-tiering figure of merit gated by check_serve_regression; fleet_resyncs: bucket fidx re-scatters
     # after a fleet compaction moved row indices
     sweeps_skipped: int
     sweep_elements: int
@@ -280,14 +280,18 @@ class _BucketLanes:
 
 # -- jitted engine programs (module-level: shapes + statics key compiles) ---
 
+def _sweeps(levels: int, n_pad: int, width: int) -> int:
+    """Sweeps of one trisolve (``trisolve_fleet``) as the engine's
+    accounting models them: a level each, plus ``n_pad / width``."""
+    return 0 if levels <= 1 else levels - 1 + -(-n_pad // width)
+
+
 def _admit_program(fa: FleetArrays, state: FleetPCGState, rows, B, fidx,
-                   tol, maxiter, *, f_levels: int, b_levels: int,
-                   kind: str = "factor"):
+                   tol, maxiter, **statics):
     """Initialize the admitted columns (same math as a direct solve's
     init) and scatter every carry field into the resident state at
     ``rows``.  Padding rows carry ``rows == slots`` and drop."""
-    init = pcg_fleet_init(fa, fidx, B, tol, maxiter,
-                          f_levels=f_levels, b_levels=b_levels, kind=kind)
+    init = pcg_fleet_init(fa, fidx, B, tol, maxiter, **statics)
     new = FleetPCGState(
         X=state.X.at[rows].set(init.X, mode="drop"),
         R=state.R.at[rows].set(init.R, mode="drop"),
@@ -304,9 +308,8 @@ def _admit_program(fa: FleetArrays, state: FleetPCGState, rows, B, fidx,
 
 
 def _step_program(fa: FleetArrays, state: FleetPCGState, *, k: int,
-                  f_levels: int, b_levels: int, kind: str = "factor"):
-    return pcg_fleet_step(fa, state, k=k, f_levels=f_levels,
-                          b_levels=b_levels, kind=kind)
+                  **statics):
+    return pcg_fleet_step(fa, state, k=k, **statics)
 
 
 def _gather_program(state: FleetPCGState, rows):
@@ -445,17 +448,14 @@ class SolveEngine:
         counts = self.compile_counts
         k = iters_per_tick
 
-        def admit(fa, state, rows, B, fidx, tol, maxiter, *,
-                  f_levels, b_levels, kind):
+        def admit(fa, state, rows, B, fidx, tol, maxiter, **statics):
             counts["admit"] += 1
             return _admit_program(fa, state, rows, B, fidx, tol, maxiter,
-                                  f_levels=f_levels, b_levels=b_levels,
-                                  kind=kind)
+                                  **statics)
 
-        def step(fa, state, *, f_levels, b_levels, kind):
+        def step(fa, state, **statics):
             counts["step"] += 1
-            return _step_program(fa, state, k=k, f_levels=f_levels,
-                                 b_levels=b_levels, kind=kind)
+            return _step_program(fa, state, k=k, **statics)
 
         def gather(state, rows):
             counts["gather"] += 1
@@ -469,10 +469,10 @@ class SolveEngine:
             counts["sync"] += 1
             return _sync_program(state, rows, fidx)
 
-        self._admit_fn = jax.jit(
-            admit, static_argnames=("f_levels", "b_levels", "kind"))
-        self._step_fn = jax.jit(
-            step, static_argnames=("f_levels", "b_levels", "kind"))
+        # FactorFleet.apply_statics: one compile per distinct value
+        statics = ("kind", "f_levels", "b_levels", "f_width", "b_width")
+        self._admit_fn = jax.jit(admit, static_argnames=statics)
+        self._step_fn = jax.jit(step, static_argnames=statics)
         self._gather_fn = jax.jit(gather)
         self._evict_fn = jax.jit(evict)
         self._sync_fn = jax.jit(sync)
@@ -610,8 +610,7 @@ class SolveEngine:
             state, act0 = self._admit_fn(
                 fleet.arrays, bl.state, jnp.asarray(rows_a),
                 jnp.asarray(B), jnp.asarray(fidx), jnp.asarray(tol),
-                jnp.asarray(maxv), f_levels=fleet.f_levels,
-                b_levels=fleet.b_levels, kind=fleet.kind)
+                jnp.asarray(maxv), **fleet.apply_statics)
             bl.state = state
             act0 = np.asarray(act0)[:j]
             bl.n_active += int(act0.sum())
@@ -642,10 +641,8 @@ class SolveEngine:
             if not occ:
                 continue
             if bl.n_active > 0:
-                bl.state = self._step_fn(
-                    bl.fleet.arrays, bl.state,
-                    f_levels=bl.fleet.f_levels, b_levels=bl.fleet.b_levels,
-                    kind=bl.fleet.kind)
+                bl.state = self._step_fn(bl.fleet.arrays, bl.state,
+                                         **bl.fleet.apply_statics)
                 self._account_sweeps(bl, occ)
             active = np.asarray(bl.state.active)   # (slots,) flags only
             frozen = [i for i in occ if not active[i]]
@@ -684,12 +681,13 @@ class SolveEngine:
     def _account_sweeps(self, bl: _BucketLanes, occ: List[int]) -> None:
         """Host-side mirror of one stepped bucket's trisolve sweep work.
 
-        ``sweep_elements`` counts the padded panel elements one
+        ``sweep_elements`` estimates the padded panel elements one
         preconditioner apply sweeps across the bucket's occupied lanes —
-        ``lanes × n_pad × (Kf · fwd sweeps + Kb · bwd sweeps)`` for
-        factor kinds (a level loop runs ``live_levels − 1`` sweeps over
-        the full ``(n_pad, K)`` panel), ``lanes × n_pad × Kf`` for spmv
-        kinds.  This is the padding tax K-tiering shrinks: untiered, a
+        ``lanes × (f_width · Kf · fwd sweeps + b_width · Kb · bwd
+        sweeps)`` for factor kinds, counting ``live_levels − 1 +
+        n_pad / width`` sweeps per trisolve at full panel width (the
+        sweeps' panel classes are not modelled), ``lanes × n_pad × Kf``
+        for spmv kinds.  This is the padding tax K-tiering shrinks: untiered, a
         hub-heavy bucket-mate inflates ``Kf``/``Kb`` for every lane
         here.  ``sweeps_skipped`` counts the level sweeps the dynamic
         per-lane bounds elided vs the static bucket ceilings."""
@@ -701,8 +699,10 @@ class SolveEngine:
                          for i in occ)
             self.sweeps_skipped += (fl.f_levels - live_f) \
                 + (fl.b_levels - live_b)
-            per_lane = fl.n_pad * (fl.Kf * max(live_f - 1, 0)
-                                   + fl.Kb * max(live_b - 1, 0))
+            per_lane = (
+                fl.f_width * fl.Kf * _sweeps(live_f, fl.n_pad, fl.f_width)
+                + fl.b_width * fl.Kb * _sweeps(live_b, fl.n_pad,
+                                               fl.b_width))
         else:
             per_lane = fl.n_pad * fl.Kf
         self.sweep_elements += len(occ) * per_lane

@@ -91,8 +91,8 @@ from repro.core.parac import factorize_wavefront, _run_engine, _build_pool
 from repro.core.trisolve import make_preconditioner
 from repro.core.laplacian import laplacian_matvec_np
 
-from repro.launch.mesh import mesh_axis_types
-mesh = jax.make_mesh((8,), ("data",), **mesh_axis_types(1))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 g = graphs.grid2d(12, 12, seed=1)
 
 # sharded SpMV == host matvec

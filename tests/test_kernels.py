@@ -129,6 +129,25 @@ def test_ell_spmv_matches_ref(R, K, n):
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("L,R,K,n", [(1, 64, 8, 64), (3, 128, 9, 256),
+                                     (2, 40, 7, 100)])
+def test_ell_spmv_fleet_xla_matches_pallas(L, R, K, n):
+    """The served fleet SpMV (XLA gather) and the Pallas fleet kernel
+    (interpret mode) compute the same per-lane product."""
+    from repro.kernels.spmv import ell_spmv_fleet_pallas
+    rng = np.random.default_rng(L * 100 + R + K)
+    cols = jnp.asarray(rng.integers(0, n, (L, R, K)), jnp.int32)
+    vals = jnp.asarray(rng.normal(size=(L, R, K)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(L, n)), jnp.float32)
+    y = np.asarray(kops.ell_spmv_fleet(cols, vals, x))
+    yp = np.asarray(ell_spmv_fleet_pallas(cols, vals, x, interpret=True))
+    yr = np.stack([np.asarray(kref.ell_spmv_ref(cols[i], vals[i], x[i]))
+                   for i in range(L)])
+    assert y.shape == (L, R)
+    np.testing.assert_allclose(y, yp, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(y, yr, rtol=1e-4, atol=1e-5)
+
+
 def test_spmv_laplacian_consistency():
     """ELL SpMV against the edge-list Laplacian matvec."""
     from repro.data import graphs

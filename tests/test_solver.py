@@ -153,6 +153,98 @@ def test_masked_trisolve_matches_host(g_small):
                                rtol=3e-4, atol=3e-4)
 
 
+def test_fleet_trisolve_level_window_is_bit_exact(g_small):
+    """The fleet trisolve's level window (each sweep gathers only the
+    rows of its level, up to the widest level) changes the work, never
+    a result: bit-identical to sweeping every row, and to the host
+    oracle within float32 tolerance."""
+    from repro.core.trisolve import build_schedules_batched
+    f = factorize_sequential(g_small, KEY)
+    fwd_h, bwd_h = build_schedules(f)
+    (fwd_p, bwd_p), = build_schedules_batched([f.to_device()])
+    B = np.random.default_rng(7).normal(size=(3, f.n)).astype(np.float32)
+    Bp = jnp.zeros((3, fwd_p.n_pad), jnp.float32).at[:, :f.n].set(B)
+    for p, flip, h in ((fwd_p, False, fwd_h), (bwd_p, True, bwd_h)):
+        assert 1 <= p.level_width < f.n
+        lanes = [jnp.stack([a] * 3) for a in (p.cols, p.vals, p.level_of)]
+        full = kops.trisolve_fleet(*lanes, Bp, n_levels=p.n_levels)
+        win = kops.trisolve_fleet(*lanes, Bp, n_levels=p.n_levels,
+                                  width=p.level_width)
+        assert np.array_equal(np.asarray(full), np.asarray(win))
+        for j in range(3):
+            np.testing.assert_allclose(
+                np.asarray(win)[j, :f.n], solve_levels_np(h, B[j], flip=flip),
+                rtol=3e-4, atol=3e-4)
+
+
+def _random_panels(rng, n, K, n_levels):
+    """A random unit-lower-triangular solve as row-indexed panels: row
+    ``i``'s in-edges come from earlier rows of lower level, with in-
+    degrees spread over 1..K so every panel class (8, 16, …, K) occurs.
+    Returns cols, vals, level_of and the dense strictly-lower matrix."""
+    level = np.sort(rng.integers(0, n_levels, n))
+    level[0] = 0
+    cols = np.zeros((n, K), np.int32)
+    vals = np.zeros((n, K), np.float32)
+    dense = np.zeros((n, n))
+    for i in range(n):
+        src = np.flatnonzero(level < level[i])
+        if level[i] == 0 or src.size == 0:
+            level[i] = 0
+            continue
+        d = int(min(src.size, rng.choice([1, 3, 8, 9, 17, K])))
+        pick = rng.choice(src, d, replace=False)
+        cols[i, :d] = pick
+        vals[i, :d] = rng.normal(size=d) / d
+        dense[i, pick] = vals[i, :d]
+    # longest-path levels of the picked edges
+    lv = np.zeros(n, np.int32)
+    for i in range(n):
+        src = cols[i, :np.count_nonzero(vals[i])]
+        lv[i] = 1 + lv[src].max() if src.size else 0
+    return cols, vals, lv, dense
+
+
+def test_fleet_trisolve_panel_classes_are_bit_exact():
+    """Rows of wide and narrow panel classes in one solve: the sweep
+    width, the plan's source (in-program or host-built) and the other
+    lanes of a batch change the work, never a lane's result, and every
+    lane matches a dense float64 solve."""
+    from repro.core.trisolve import sweep_plan_np
+    rng = np.random.default_rng(3)
+    n, K = 96, 32
+    lanes = [_random_panels(rng, n, K, 9) for _ in range(2)]
+    y0 = rng.normal(size=(2, n)).astype(np.float32)
+    cols = jnp.asarray(np.stack([c for c, _, _, _ in lanes]))
+    vals = jnp.asarray(np.stack([v for _, v, _, _ in lanes]))
+    lvl = jnp.asarray(np.stack([l for _, _, l, _ in lanes]))
+    nl = int(lvl.max()) + 1
+    ext = (np.asarray(vals) != 0).sum(axis=2)
+    host = [sweep_plan_np(np.asarray(lvl[j]), ext[j], nl) for j in range(2)]
+    host_plan = tuple(jnp.asarray(np.stack(a)) for a in zip(*host))
+    dev_plan = kops.sweep_plan(vals, lvl, nl)
+    for a, b in zip(host_plan, dev_plan):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    ref = kops.trisolve_fleet(cols, vals, lvl, jnp.asarray(y0), n_levels=nl)
+    # with a plan, panels come in plan order (as a fleet stores them)
+    by_plan = [jnp.take_along_axis(a, host_plan[0][:, :, None], axis=1)
+               for a in (cols, vals)]
+    for width in (1, 5, 16):
+        got = kops.trisolve_fleet(*by_plan, lvl, jnp.asarray(y0),
+                                  n_levels=nl, width=width, plan=host_plan)
+        assert np.array_equal(np.asarray(got), np.asarray(ref))
+    # lane 0 alone, and beside a copy of itself, as in the pair above
+    for pick in ([0], [0, 0]):
+        ix = jnp.asarray(pick)
+        got = kops.trisolve_fleet(cols[ix], vals[ix], lvl[ix],
+                                  jnp.asarray(y0)[ix], n_levels=nl, width=5)
+        assert np.array_equal(np.asarray(got)[0], np.asarray(ref)[0])
+    for j, (_, _, _, dense) in enumerate(lanes):
+        want = np.linalg.solve(np.eye(n) + dense, y0[j].astype(np.float64))
+        np.testing.assert_allclose(np.asarray(ref)[j], want, rtol=1e-4,
+                                   atol=1e-4)
+
+
 def test_pallas_panel_trisolve_matches_host(g_small):
     f = factorize_sequential(g_small, KEY)
     fwd_h, bwd_h = build_schedules(f)
@@ -311,6 +403,20 @@ def test_strict_overflow_retry_doubles_slack(g_small):
     assert np.array_equal(f.rows, f_ref.rows)
     assert np.array_equal(f.vals, f_ref.vals)
     assert np.array_equal(f.D, f_ref.D)
+
+
+def test_strict_batched_retry_never_drops_fill(g_small):
+    # the batched path retries only the overflowing graphs, as often as
+    # it takes: a strict factor never carries dropped fill
+    from repro.core.parac import factorize_batched
+    gs = [g_small, graphs.grid2d(10, 14, seed=5)]
+    keys = jnp.stack([KEY, jax.random.key(8)])
+    fs = factorize_batched(gs, keys, chunk=32, fill_slack=1, strict=True)
+    for g, f, key in zip(gs, fs, keys):
+        assert f.stats["overflow"] == 0
+        ref = factorize_wavefront(g, key, chunk=32, fill_slack=64)
+        assert np.array_equal(f.rows, ref.rows)
+        assert np.array_equal(f.vals, ref.vals)
 
 
 # ---------------------------------------------------------------------------

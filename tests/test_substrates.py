@@ -182,8 +182,8 @@ from repro.configs.shapes import ShapeCell
 from repro.distributed.steps import make_train_step, make_abstract_inputs
 from repro.configs.shapes import input_specs
 
-from repro.launch.mesh import mesh_axis_types
-mesh = jax.make_mesh((2, 4), ("data", "model"), **mesh_axis_types(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = dataclasses.replace(get_smoke_config("qwen3-14b"), d_model=64,
                           n_heads=8, n_kv_heads=4, head_dim=16,
                           d_ff=256, vocab=1024)
