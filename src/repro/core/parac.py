@@ -52,6 +52,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs.tracing import span
 from .laplacian import Graph
 from .column_math import eliminate_column, column_uniforms, INVALID_ID
 from .ref_ac import ACFactor, DeviceFactor
@@ -351,19 +352,25 @@ def factorize_wavefront(g: Graph, key: jax.Array, *, chunk: int = 64,
     n = g.n
     slack = fill_slack
     while True:
-        (pool_row, pool_val, fill, dep, col_base, cap, P, dmax) = \
-            _build_pool(g, slack, dtype)
-        final = _run_engine(
-            jnp.asarray(pool_row), jnp.asarray(pool_val), jnp.asarray(fill),
-            jnp.asarray(dep), jnp.asarray(col_base), jnp.asarray(cap), key,
-            dmax=dmax, chunk=min(chunk, max(n, 1)))
-        ovf = int(final.overflow)
+        with span("construct/pool"):
+            (pool_row, pool_val, fill, dep, col_base, cap, P, dmax) = \
+                _build_pool(g, slack, dtype)
+        with span("construct/eliminate"):
+            final = _run_engine(
+                jnp.asarray(pool_row), jnp.asarray(pool_val),
+                jnp.asarray(fill), jnp.asarray(dep), jnp.asarray(col_base),
+                jnp.asarray(cap), key, dmax=dmax,
+                chunk=min(chunk, max(n, 1)))
+            ovf = int(final.overflow)
+            rounds = int(final.n_rounds)
         if ovf == 0 or not strict:
             break
         slack = _next_strict_slack(g, slack, ovf)
-    stats = dict(rounds=int(final.n_rounds), overflow=ovf,
+    stats = dict(rounds=rounds, overflow=ovf,
                  chunk=chunk, fill_slack=slack, pool_size=P, dmax=dmax)
-    return _finalize_factor(g, final, jnp.asarray(col_base), stats=stats)
+    with span("construct/finalize"):
+        return _finalize_factor(g, final, jnp.asarray(col_base),
+                                stats=stats)
 
 
 # ---------------------------------------------------------------------------
@@ -431,7 +438,9 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
     results: List[Optional[ACFactor]] = [None] * B
     pending = list(range(B))
     while pending:
-        built = {i: _build_pool(gs[i], slacks[i], dtype) for i in pending}
+        with span("construct/pool"):
+            built = {i: _build_pool(gs[i], slacks[i], dtype)
+                     for i in pending}
         n_pad = max(max(gs[i].n for i in pending), 1)
         P_pad = max(max(built[i][6] for i in pending), 1)
         dmax_pad = max(built[i][7] for i in pending)
@@ -454,28 +463,33 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
             elim0 = np.zeros(n_pad, bool)
             elim0[n:] = True
             E0.append(elim0)
-        out = _run_engine_batched(
-            jnp.asarray(np.stack(PR)), jnp.asarray(np.stack(PV)),
-            jnp.asarray(np.stack(CF)), jnp.asarray(np.stack(DP)),
-            jnp.asarray(np.stack(CB)), jnp.asarray(np.stack(CP)),
-            jnp.asarray(np.stack(E0)),
-            jnp.stack([keys[i] for i in pending]),
-            dmax=dmax_pad, chunk=chunk_eff)
+        with span("construct/eliminate"):
+            out = _run_engine_batched(
+                jnp.asarray(np.stack(PR)), jnp.asarray(np.stack(PV)),
+                jnp.asarray(np.stack(CF)), jnp.asarray(np.stack(DP)),
+                jnp.asarray(np.stack(CB)), jnp.asarray(np.stack(CP)),
+                jnp.asarray(np.stack(E0)),
+                jnp.stack([keys[i] for i in pending]),
+                dmax=dmax_pad, chunk=chunk_eff)
+            overflow = np.asarray(out.overflow)
+            rounds = np.asarray(out.n_rounds)
 
         retry = []
         for bi, i in enumerate(pending):
-            final_i = jax.tree_util.tree_map(lambda x, bi=bi: x[bi], out)
-            ovf = int(final_i.overflow)
+            ovf = int(overflow[bi])
             if ovf == 0 or not strict:
-                stats = dict(rounds=int(final_i.n_rounds), overflow=ovf,
+                stats = dict(rounds=int(rounds[bi]), overflow=ovf,
                              chunk=chunk, fill_slack=slacks[i],
                              pool_size=int(built[i][6]),
                              dmax=int(built[i][7]), batched=True,
                              batch_size=len(pending), n_pad=n_pad,
                              P_pad=P_pad, dmax_pad=dmax_pad)
-                results[i] = _finalize_factor(
-                    gs[i], final_i, jnp.asarray(CB[bi]),
-                    n_phantom=n_pad - gs[i].n, stats=stats)
+                final_i = jax.tree_util.tree_map(lambda x, bi=bi: x[bi],
+                                                 out)
+                with span("construct/finalize"):
+                    results[i] = _finalize_factor(
+                        gs[i], final_i, jnp.asarray(CB[bi]),
+                        n_phantom=n_pad - gs[i].n, stats=stats)
             else:
                 slacks[i] = _next_strict_slack(gs[i], slacks[i], ovf)
                 retry.append(i)
@@ -483,4 +497,6 @@ def factorize_batched(gs: Sequence[Graph], keys, *, chunk: int = 64,
     if not with_schedules:
         return results
     from .trisolve import build_schedules_batched
-    return results, build_schedules_batched([f.device for f in results])
+    with span("construct/schedules"):
+        return results, build_schedules_batched(
+            [f.device for f in results])

@@ -254,6 +254,7 @@ class FleetPCGState(NamedTuple):
     maxiter: jnp.ndarray  # int32 (L,)
 
 
+@jax.named_scope("fleet_matvec")
 def fleet_matvec(fa: FleetArrays, fidx: jnp.ndarray,
                  Y: jnp.ndarray) -> jnp.ndarray:
     """Per-lane Laplacian matvec: lane ``l`` multiplies by the operator
@@ -321,7 +322,8 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
                        n_levels=f_levels, lane_levels=flv, width=f_width,
                        plan=(fa.forder[fidx], fa.fext[fidx], fa.fgend[fidx],
                              fa.fptr[fidx, :f_levels + 1]))
-    Z = Y * fa.dinv[fidx]
+    with jax.named_scope("pcg_update"):
+        Z = Y * fa.dinv[fidx]
     return trisolve_fleet(fa.bcols, fa.bvals, None, Z, fidx=fidx,
                           n_levels=b_levels, lane_levels=blv,
                           width=b_width,
@@ -329,6 +331,7 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
                                 fa.bgend[fidx], fa.bptr[fidx, :b_levels + 1]))
 
 
+@jax.named_scope("pcg_update")
 def _fleet_project(Y: jnp.ndarray, nvalid: jnp.ndarray) -> jnp.ndarray:
     """Mean-zero projection restricted to each lane's true vertices.
     Padding entries are forced (back) to exactly 0 so padded reductions
@@ -350,25 +353,27 @@ def pcg_fleet_init(fa: FleetArrays, fidx, B, tol, maxiter, *,
     factor ``fidx[l]``.  ``kind`` is the fleet's static apply kind (see
     :func:`fleet_precondition`)."""
     fidx = jnp.asarray(fidx, jnp.int32)
-    nvalid = fa.nvalid[fidx]
-    if project:
-        B = _fleet_project(B, nvalid)
-    bnorm = jnp.linalg.norm(B, axis=1)
-    bnorm = jnp.where(bnorm > 0, bnorm, 1.0)
+    with jax.named_scope("pcg_update"):
+        nvalid = fa.nvalid[fidx]
+        if project:
+            B = _fleet_project(B, nvalid)
+        bnorm = jnp.linalg.norm(B, axis=1)
+        bnorm = jnp.where(bnorm > 0, bnorm, 1.0)
     R0 = B
     Z0 = fleet_precondition(fa, fidx, R0, f_levels=f_levels,
                             b_levels=b_levels, kind=kind, f_width=f_width,
                             b_width=b_width)
-    if project:
-        Z0 = _fleet_project(Z0, nvalid)
-    rz0 = jnp.sum(R0 * Z0, axis=1)
-    act0 = (jnp.linalg.norm(B, axis=1) / bnorm) > tol
-    L = B.shape[0]
-    return FleetPCGState(
-        X=jnp.zeros_like(B), R=R0, Z=Z0, P=Z0, rz=rz0,
-        it=jnp.zeros(L, jnp.int32), active=act0, bnorm=bnorm, fidx=fidx,
-        tol=jnp.asarray(tol, jnp.float32),
-        maxiter=jnp.asarray(maxiter, jnp.int32))
+    with jax.named_scope("pcg_update"):
+        if project:
+            Z0 = _fleet_project(Z0, nvalid)
+        rz0 = jnp.sum(R0 * Z0, axis=1)
+        act0 = (jnp.linalg.norm(B, axis=1) / bnorm) > tol
+        L = B.shape[0]
+        return FleetPCGState(
+            X=jnp.zeros_like(B), R=R0, Z=Z0, P=Z0, rz=rz0,
+            it=jnp.zeros(L, jnp.int32), active=act0, bnorm=bnorm,
+            fidx=fidx, tol=jnp.asarray(tol, jnp.float32),
+            maxiter=jnp.asarray(maxiter, jnp.int32))
 
 
 def _pcg_fleet_body(fa: FleetArrays, *, f_levels: int, b_levels: int,
@@ -381,13 +386,14 @@ def _pcg_fleet_body(fa: FleetArrays, *, f_levels: int, b_levels: int,
     row and its own factor's fleet rows, so trajectories do not depend
     on batch composition, padding lanes, or step slicing."""
     def body(s: FleetPCGState) -> FleetPCGState:
-        nvalid = fa.nvalid[s.fidx]
         AP = fleet_matvec(fa, s.fidx, s.P)
-        pAp = jnp.sum(s.P * AP, axis=1)
-        alpha = jnp.where(s.active,
-                          s.rz / jnp.where(pAp != 0, pAp, 1.0), 0.0)
-        Xn = s.X + alpha[:, None] * s.P
-        Rn = s.R - alpha[:, None] * AP
+        with jax.named_scope("pcg_update"):
+            nvalid = fa.nvalid[s.fidx]
+            pAp = jnp.sum(s.P * AP, axis=1)
+            alpha = jnp.where(s.active,
+                              s.rz / jnp.where(pAp != 0, pAp, 1.0), 0.0)
+            Xn = s.X + alpha[:, None] * s.P
+            Rn = s.R - alpha[:, None] * AP
         if project:
             # L·P is mean-zero only up to rounding, and the factor's
             # preconditioner amplifies a constant residual component:
@@ -399,22 +405,23 @@ def _pcg_fleet_body(fa: FleetArrays, *, f_levels: int, b_levels: int,
                                 active=s.active)
         if project:
             Zn = _fleet_project(Zn, nvalid)
-        rz_new = jnp.sum(Rn * Zn, axis=1)
-        beta = jnp.where(s.active,
-                         rz_new / jnp.where(s.rz != 0, s.rz, 1.0), 0.0)
-        Pn = Zn + beta[:, None] * s.P
-        m = s.active[:, None]
-        X = jnp.where(m, Xn, s.X)
-        R = jnp.where(m, Rn, s.R)
-        Z = jnp.where(m, Zn, s.Z)
-        P = jnp.where(m, Pn, s.P)
-        rz = jnp.where(s.active, rz_new, s.rz)
-        it = s.it + s.active.astype(jnp.int32)
-        relres = jnp.linalg.norm(R, axis=1) / s.bnorm
-        active = s.active & (relres > s.tol) & (it < s.maxiter)
-        return FleetPCGState(X=X, R=R, Z=Z, P=P, rz=rz, it=it,
-                             active=active, bnorm=s.bnorm, fidx=s.fidx,
-                             tol=s.tol, maxiter=s.maxiter)
+        with jax.named_scope("pcg_update"):
+            rz_new = jnp.sum(Rn * Zn, axis=1)
+            beta = jnp.where(s.active,
+                             rz_new / jnp.where(s.rz != 0, s.rz, 1.0), 0.0)
+            Pn = Zn + beta[:, None] * s.P
+            m = s.active[:, None]
+            X = jnp.where(m, Xn, s.X)
+            R = jnp.where(m, Rn, s.R)
+            Z = jnp.where(m, Zn, s.Z)
+            P = jnp.where(m, Pn, s.P)
+            rz = jnp.where(s.active, rz_new, s.rz)
+            it = s.it + s.active.astype(jnp.int32)
+            relres = jnp.linalg.norm(R, axis=1) / s.bnorm
+            active = s.active & (relres > s.tol) & (it < s.maxiter)
+            return FleetPCGState(X=X, R=R, Z=Z, P=P, rz=rz, it=it,
+                                 active=active, bnorm=s.bnorm, fidx=s.fidx,
+                                 tol=s.tol, maxiter=s.maxiter)
 
     return body
 

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.kernels.runtime import pad_k
 from repro.obs.flight import NULL_FLIGHT
+from repro.obs.tracing import note_program, span
 from .laplacian import Graph
 from .ref_ac import ACFactor, DeviceFactor
 from .parac import factorize_wavefront, factorize_batched, _next_pow2
@@ -576,6 +577,7 @@ class PreconditionerHandle:
     max_age_ticks: Optional[int] = None
     _cache: "OrderedDict[Tuple, Callable]" = dataclasses.field(
         default_factory=OrderedDict)
+    _sweeps: Optional[Tuple[Tuple, int]] = None
 
     @property
     def n(self) -> int:
@@ -614,6 +616,32 @@ class PreconditionerHandle:
             own = 0
         return own + self.fleet.bytes_per_row
 
+    @property
+    def sweeps_per_apply(self) -> int:
+        """Sweeps of one forward plus one backward triangular solve of
+        this factor for one lane, as ``trisolve_fleet`` runs them: the
+        sweep plan the fleet stores, walked on first read (and again
+        once the fleet's panel or sweep widths change).  0 for
+        ``"spmv"`` kinds, whose apply runs no sweep."""
+        fl = self.fleet
+        if fl.kind != "factor":
+            return 0
+        key = (fl.Kf, fl.Kb, fl.f_width, fl.b_width, fl.f_levels,
+               fl.b_levels)
+        if self._sweeps is None or self._sweeps[0] != key:
+            from repro.kernels.ops import trisolve_sweeps
+            a, row = fl.arrays, self.fleet_row
+            # whole host copies: an eager slice compiles a program
+            row_of = lambda x: np.asarray(x)[row]  # noqa: E731
+            count = sum(
+                trisolve_sweeps(row_of(ext), row_of(gend), row_of(ptr),
+                                int(row_of(nlv)), K, width)
+                for ext, gend, ptr, nlv, K, width in (
+                    (a.fext, a.fgend, a.fptr, a.fnlv, fl.Kf, fl.f_width),
+                    (a.bext, a.bgend, a.bptr, a.bnlv, fl.Kb, fl.b_width)))
+            self._sweeps = (key, count)
+        return self._sweeps[1]
+
     def matvec(self, x: jnp.ndarray) -> jnp.ndarray:
         """``L x`` through the handle's fleet row (the padded edge lists
         already resident in the bucket stack — no per-handle copies)."""
@@ -647,24 +675,28 @@ class PreconditionerHandle:
         Runs the fleet PCG one-shot loop over the handle's bucket
         arrays — the same body a :class:`serve.SolveEngine` ticks, so a
         served request reproduces these iterates bit-exactly."""
-        B = jnp.asarray(B)
-        if B.ndim not in (1, 2) or B.shape[-1] != self.n:
-            raise ValueError(
-                f"rhs must be (n,) or (nrhs, n) with n={self.n}, "
-                f"got {B.shape}")
-        statics = self.fleet.apply_statics
-        key = (B.shape, str(B.dtype), float(tol), int(maxiter), project,
-               *sorted(statics.items()))
-        fn = self._cache.get(key)
-        if fn is None:
-            fn = jax.jit(self._build_solve(B.ndim, tol, maxiter, project,
-                                           statics))
-            self._cache[key] = fn
-            while len(self._cache) > self.max_cached_solves:
-                self._cache.popitem(last=False)
-        else:
-            self._cache.move_to_end(key)
-        return fn(B, self.fleet.arrays, jnp.int32(self.fleet_row))
+        with span("solver/solve"):
+            B = jnp.asarray(B)
+            if B.ndim not in (1, 2) or B.shape[-1] != self.n:
+                raise ValueError(
+                    f"rhs must be (n,) or (nrhs, n) with n={self.n}, "
+                    f"got {B.shape}")
+            statics = self.fleet.apply_statics
+            key = (B.shape, str(B.dtype), float(tol), int(maxiter), project,
+                   *sorted(statics.items()))
+            fn = self._cache.get(key)
+            if fn is None:
+                fn = jax.jit(self._build_solve(B.ndim, tol, maxiter, project,
+                                               statics))
+                self._cache[key] = fn
+                while len(self._cache) > self.max_cached_solves:
+                    self._cache.popitem(last=False)
+            else:
+                self._cache.move_to_end(key)
+            args = (B, self.fleet.arrays, jnp.int32(self.fleet_row))
+            out = fn(*args)
+            note_program(fn, *args)
+            return out
 
     def _build_solve(self, ndim: int, tol: float, maxiter: int,
                      project: bool, statics: Dict):
@@ -1044,14 +1076,17 @@ class FactorCache:
         for g, f, schedules, gid, family in items:
             fam = get_family(family)
             if fam.kind == "spmv":
-                pf = _PaddedFactor.from_ell(g, f)
+                with span("construct/pack"):
+                    pf = _PaddedFactor.from_ell(g, f)
                 fwd, bwd = pf.fwd, pf.bwd
             else:
                 dev = f.to_device()
                 if schedules is None:
-                    schedules = build_schedules_batched([dev])[0]
+                    with span("construct/schedules"):
+                        schedules = build_schedules_batched([dev])[0]
                 fwd, bwd = schedules
-                pf = _PaddedFactor(g, dev, fwd, bwd)
+                with span("construct/pack"):
+                    pf = _PaddedFactor(g, dev, fwd, bwd)
             # pow2 K-tier on the padded panel width (max of both panel
             # sets — the tier must cover whichever trisolve is wider);
             # tier 0 = tiering disabled, one fleet per (family, n_pad)
@@ -1080,7 +1115,8 @@ class FactorCache:
             by_fleet.setdefault((fleet.family, fleet.n_pad, fleet.k_tier),
                                 []).append((handle, pf))
         for fkey, pairs in by_fleet.items():
-            rows = self._fleets[fkey].admit_many(pairs)
+            with span("construct/admit"):
+                rows = self._fleets[fkey].admit_many(pairs)
             for (handle, _), row in zip(pairs, rows):
                 handle.fleet_row = row
         out: List[Tuple[str, FactorHandle]] = []

@@ -126,6 +126,7 @@ def trisolve_levels(level_rows, level_cols, level_vals, b, flip: bool = False,
 
 
 @jax.jit
+@jax.named_scope("ell_spmv_fleet")
 def ell_spmv_fleet(cols, vals, x):
     """Lane-batched ELL SpMV; cols/vals: [L, R, K], x: [L, n] → [L, R].
 
@@ -233,6 +234,34 @@ def _k_classes(K: int):
     return tuple(classes)
 
 
+def trisolve_sweeps(extent, group_end, level_ptr, n_levels: int, K: int,
+                    width: int) -> int:
+    """The sweeps :func:`trisolve_fleet` makes for one lane through one
+    triangular solve, counted on the host from the lane's sweep plan
+    (``extent``/``group_end`` in plan order, ``level_ptr`` the level
+    starts, ``n_levels`` the factor's true level count) for panels
+    ``K`` slots wide and ``width`` rows per sweep of the narrowest
+    class — the same walk as the program's loop."""
+    extent, group_end = np.asarray(extent), np.asarray(group_end)
+    level_ptr = np.asarray(level_ptr)
+    n = int(extent.shape[0])
+    classes = _k_classes(int(K))
+    cls_of = np.maximum(
+        1 << np.ceil(np.log2(np.maximum(extent, 1))).astype(np.int64), 8)
+    rows_c = max(1, min(int(width), n))
+    bound = min(max(int(n_levels), 1), int(level_ptr.shape[0]) - 1)
+    start, stop = int(level_ptr[1]), int(level_ptr[bound])
+    sweeps = 0
+    while start < stop:
+        kc = next((k for k in classes[:-1] if k >= cls_of[start]),
+                  classes[-1])
+        rows_k = max(1, rows_c * classes[0] // kc)
+        start = min(start + rows_k, int(group_end[start]))
+        sweeps += 1
+    return sweeps
+
+
+@jax.named_scope("trisolve_fleet")
 def trisolve_fleet(cols, vals, level_of, y, *, n_levels: int,
                    lane_levels=None, width=None, plan=None, fidx=None):
     """Lane-batched level-scheduled unit-triangular solve: cols/vals

@@ -1,7 +1,8 @@
-"""Per-request lifecycle tracing: span records and Chrome trace export.
+"""Tracing: per-request lifecycle spans, and in-program spans and
+kernel scopes on the profiler's clock.
 
-A request's wall-clock decomposes into a contiguous partition of
-``[submit_time, finish_time]``::
+**Request lifecycle.**  A request's wall-clock decomposes into a
+contiguous partition of ``[submit_time, finish_time]``::
 
     route          submit .. +route_s          router decision + retries
     factor|adopt   .. +factor_wait_s           cold-path construction wait
@@ -27,17 +28,61 @@ directly in ``chrome://tracing`` / Perfetto.  ``pid`` is the replica
 (one track group per replica), ``tid`` is the request id (one row per
 request), so a request's spans nest on their own row and cross-replica
 interleaving is visible at a glance.
+
+**Program spans** (:data:`PROGRAM_SPANS`, entered with :func:`span`)
+are profiler annotations (``jax.profiler.TraceAnnotation``) around
+the host phases of the serving path and of construction: they land in
+a profiler trace on the same clock as the device's op rows, so an idle
+gap on the device can be put down to what the host was doing.  With no
+profiler session running a span costs one enabled-check; it never
+syncs with the device, so it ends where its host code ends and the
+device time under it is read off the trace.
+
+**Kernel scopes** (:data:`KERNEL_SCOPES`) are ``jax.named_scope``
+names on the solve kernels: compiled in, they name each HLO
+instruction's ``op_name``.  A device op belongs to the outermost kernel
+scope on its ``op_name`` path (a trisolve sweep's SpMV is trisolve
+time).  A TPU trace names an op by its HLO instruction alone, so while
+a profiler session runs the programs that ran are noted
+(:func:`note_program`), and :func:`scope_tables` maps each one's
+instructions to their scopes, from the executable JAX already holds.
 """
 from __future__ import annotations
 
 import json
+import re
 import threading
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+from jax.profiler import TraceAnnotation
+
 # The lifecycle stages, in partition order.
 STAGES = ("route", "factor", "adopt", "queue", "first_tick", "solve")
+
+# Host spans of the program, as they nest: an engine tick and its
+# phases; the frontend driver's control calls and submissions;
+# ``PreconditionerHandle.solve``'s dispatch; construction's stages.
+PROGRAM_SPANS = (
+    "engine/tick", "engine/admit", "engine/step", "engine/readback",
+    "engine/retire",
+    "frontend/control", "frontend/submit",
+    "solver/solve",
+    "construct/pool", "construct/eliminate", "construct/finalize",
+    "construct/schedules", "construct/pack", "construct/admit",
+)
+
+# Device-side kernel scopes (``jax.named_scope`` names).
+KERNEL_SCOPES = ("trisolve_fleet", "ell_spmv_fleet", "fleet_matvec",
+                 "pcg_update")
+
+
+def span(name: str) -> TraceAnnotation:
+    """A program span: ``with span("engine/step"): ...``.  ``name`` is
+    one of :data:`PROGRAM_SPANS`."""
+    return TraceAnnotation(name)
 
 
 @dataclass(frozen=True)
@@ -216,3 +261,165 @@ class Tracer:
             seen = self._seen
         return {"recorded": n, "seen": seen, "dropped": dropped,
                 "stage_s": self.stage_seconds()}
+
+
+# -- kernel scopes of compiled programs -------------------------------------
+
+def _spec(x):
+    """An argument as ``jit.lower`` sees it: arrays become shapes (with
+    the device of a committed array, so the lowering finds the same
+    executable), everything else stays."""
+    import jax
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    if isinstance(x, (np.ndarray, np.generic)):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return x
+
+
+_notes_lock = threading.Lock()
+_noted: set = set()                      # programs noted so far
+_todo: List[Tuple] = []                  # (fn, args, kwargs) to table
+_tables: Dict[Tuple[str, str], Dict[str, Tuple[str, Optional[str]]]] = {}
+
+
+def note_program(fn, *args, **kwargs) -> None:
+    """Note that the jitted ``fn`` just ran on ``args``/``kwargs``
+    (static arguments by keyword), while a profiler session runs; a
+    no-op otherwise.  Keeps shapes, not arrays."""
+    if not TraceAnnotation.is_enabled():
+        return
+    import jax
+    leaves, tree = jax.tree_util.tree_flatten((args, kwargs))
+    specs = [_spec(x) for x in leaves]
+    key = (fn, tree, tuple(
+        (s.shape, str(s.dtype), s.sharding is not None)
+        if isinstance(s, jax.ShapeDtypeStruct) else repr(s)
+        for s in specs))
+    with _notes_lock:
+        if key not in _noted:
+            _noted.add(key)
+            _todo.append((fn, *jax.tree_util.tree_unflatten(tree, specs)))
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The outermost kernel scope on an ``op_name`` path, or None."""
+    for part in op_name.split("/"):
+        if part in KERNEL_SCOPES:
+            return part
+    return None
+
+
+def instruction_signature(text: str) -> Tuple[str, str]:
+    """``(name, "shape opcode")`` of one HLO instruction as HLO text or
+    a TPU trace prints it (``%fusion.4 = f32[8]{0} fusion(...)``), the
+    shape without its layout; the signature is empty where the text is
+    a bare instruction name."""
+    text = text.strip()
+    if text.startswith("ROOT "):
+        text = text[5:]
+    name, eq, rest = text.partition(" = ")
+    name = name.lstrip("%")
+    if not eq:
+        return name, ""
+    if rest.startswith("("):               # a tuple shape
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape, rest = rest[:i + 1], rest[i + 1:].lstrip()
+    else:
+        shape, _, rest = rest.partition(" ")
+    shape = re.sub(r"\{[^{}]*\}", "", shape)           # layouts
+    return name, f"{shape} {rest.split('(', 1)[0]}"
+
+
+def _op_name(line: str) -> str:
+    at = line.find('op_name="')
+    if at < 0:
+        return ""
+    at += len('op_name="')
+    return line[at:line.index('"', at)]
+
+
+_CALLED = re.compile(r"(?:condition|body|calls|to_apply|true_computation"
+                     r"|false_computation)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def parse_scope_table(hlo_text: str) -> Tuple[str, Dict]:
+    """``(module name, {instruction: (signature, scope)})`` of a
+    compiled module's HLO text.  The compiler's rewrites (a gather
+    expanded into a loop, a scatter fused with its index math) leave
+    instructions with a bare or empty ``op_name``, so an instruction
+    inside a computation that a scoped instruction calls (a loop body,
+    a branch, a fusion) takes that outer scope, and one with no scope
+    of its own takes the one scope of what it calls, where that is
+    one."""
+    module = hlo_text.split(None, 2)[1].rstrip(",")
+    comp, found, callers, members = "", {}, {}, {}
+    for line in hlo_text.splitlines():
+        line = line.strip()
+        if line.endswith("{") and " = " not in line.split("(", 1)[0]:
+            comp = line.replace("ENTRY ", "").split(None, 1)[0].lstrip("%")
+            continue
+        if not line.startswith(("%", "ROOT %")) or " = " not in line:
+            continue
+        name, sig = instruction_signature(line)
+        called = _CALLED.findall(line)
+        for group in _BRANCHES.findall(line):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        found[name] = (comp, sig, scope_of(_op_name(line)), called)
+        members.setdefault(comp, []).append(name)
+        for c in called:
+            callers.setdefault(c, set()).add(name)
+    outer: Dict[str, Optional[str]] = {}
+    inner: Dict[str, set] = {}
+
+    def one(scopes) -> Optional[str]:
+        scopes = set(scopes) - {None}
+        return scopes.pop() if len(scopes) == 1 else None
+
+    def scopes_in(c: str) -> set:
+        # every scope named inside computation c, however deep
+        if c not in inner:
+            inner[c] = set()
+            for i in members.get(c, ()):
+                inner[c] |= {found[i][2]}.union(
+                    *(scopes_in(d) for d in found[i][3]))
+        return inner[c]
+
+    def scope_around(c: str) -> Optional[str]:
+        # the scope computation c runs under: its callers', if one
+        if c not in outer:
+            outer[c] = None                    # a cycle reads no scope
+            outer[c] = one(resolved(i) for i in callers.get(c, ()))
+        return outer[c]
+
+    def resolved(i: str) -> Optional[str]:
+        c, _, own, called = found[i]
+        return scope_around(c) or own or one(
+            set().union(*(scopes_in(d) for d in called)))
+
+    return module, {i: (f[1], resolved(i)) for i, f in found.items()}
+
+
+def scope_tables() -> Dict[Tuple[str, str], Dict]:
+    """Kernel-scope tables of every program noted so far, keyed by
+    ``(module name, executable fingerprint)``: each maps an HLO
+    instruction name to its signature (``"shape opcode"``) and its
+    kernel scope (None outside every scope).  Built on first read from
+    the executables JAX holds (``lower(...).compile()`` finds them in
+    its cache: no backend compile), so a call pays nothing for it."""
+    with _notes_lock:
+        todo = list(_todo)
+        _todo.clear()
+    for fn, args, kwargs in todo:
+        compiled = fn.lower(*args, **kwargs).compile()
+        module, table = parse_scope_table(compiled.as_text())
+        fp = compiled.runtime_executable().fingerprint
+        fp = fp.hex() if isinstance(fp, bytes) else str(fp)
+        _tables[(module, fp)] = table
+    return dict(_tables)

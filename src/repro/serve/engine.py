@@ -67,7 +67,7 @@ from repro.core.pcg import (FleetArrays, FleetPCGState, pcg_fleet_init,
                             pcg_fleet_step)
 from repro.obs.flight import NULL_FLIGHT
 from repro.obs.registry import NULL as _NULL_METRICS
-from repro.obs.tracing import trace_from_request
+from repro.obs.tracing import note_program, span, trace_from_request
 from repro.serve.admission import AdmissionPolicy, FIFOAdmission
 
 # process-wide trace-id sequence: stamped once per request at
@@ -607,10 +607,11 @@ class SolveEngine:
             tol = np.full(jp, req.tol, np.float32)
             maxv = np.zeros(jp, np.int32)
             maxv[:j] = req.maxiter
-            state, act0 = self._admit_fn(
-                fleet.arrays, bl.state, jnp.asarray(rows_a),
-                jnp.asarray(B), jnp.asarray(fidx), jnp.asarray(tol),
-                jnp.asarray(maxv), **fleet.apply_statics)
+            args = (fleet.arrays, bl.state, jnp.asarray(rows_a),
+                    jnp.asarray(B), jnp.asarray(fidx), jnp.asarray(tol),
+                    jnp.asarray(maxv))
+            state, act0 = self._admit_fn(*args, **fleet.apply_statics)
+            note_program(self._admit_fn, *args, **fleet.apply_statics)
             bl.state = state
             act0 = np.asarray(act0)[:j]
             bl.n_active += int(act0.sum())
@@ -628,54 +629,62 @@ class SolveEngine:
         ``iters_per_tick`` PCG iterations (one jitted step per bucket —
         all factors in the bucket ride the same program), retire finished
         lanes.  Returns requests completed this tick."""
-        t_tick0 = self._clock()
-        self._resync_buckets()
-        self._admit()
-        if self.admission.evict_hopeless:
-            self._evict_hopeless()
-        done: List[SolveRequest] = []
-        for bkey in sorted(self._buckets):
-            bl = self._buckets[bkey]
-            occ = [i for i, lane in enumerate(self.lanes)
-                   if lane is not None and lane.bucket is bl]
-            if not occ:
-                continue
-            if bl.n_active > 0:
-                bl.state = self._step_fn(bl.fleet.arrays, bl.state,
-                                         **bl.fleet.apply_statics)
-                self._account_sweeps(bl, occ)
-            active = np.asarray(bl.state.active)   # (slots,) flags only
-            frozen = [i for i in occ if not active[i]]
-            bl.n_active = int(active[occ].sum())
-            if frozen:
-                done.extend(self._retire(bl, frozen))
-        self._unpin_idle()
-        self.ticks += 1
-        self.cache.advance_ticks(1)
-        if self.tracer is not None:
-            # first host-side timestamp after a lane's first step call —
-            # only when tracing is on (the stamp loop is pure host work,
-            # but a trace nobody asked for is still overhead)
-            t_first = self._clock()
-            for lane in self.lanes:
-                if lane is not None and lane.req.first_tick_time == 0.0:
-                    lane.req.first_tick_time = t_first
-        # running *minimum* tick duration — the deadline-eviction lower
-        # bound for "one more tick".  A minimum (not a mean) is the
-        # safe estimator: compile-heavy first ticks must not inflate it
-        # and spuriously evict meetable requests; underestimating only
-        # delays eviction until the deadline has truly passed.  (An
-        # injected constant clock keeps this at 0, so tests evict
-        # exactly when the deadline passes.)
-        dur = self._clock() - t_tick0
-        self._est_tick_s = dur if self._est_tick_s == 0.0 else \
-            min(self._est_tick_s, dur)
-        self._m_ticks.inc()
-        self._m_tick_s.observe(dur)
-        self._m_queue.set(len(self.queue))
-        self._m_lanes.set(sum(l is not None for l in self.lanes))
-        if self.metrics is not None:
-            self.metrics.maybe_sample(self._clock())
+        with span("engine/tick"):
+            t_tick0 = self._clock()
+            self._resync_buckets()
+            with span("engine/admit"):
+                self._admit()
+            if self.admission.evict_hopeless:
+                self._evict_hopeless()
+            done: List[SolveRequest] = []
+            for bkey in sorted(self._buckets):
+                bl = self._buckets[bkey]
+                occ = [i for i, lane in enumerate(self.lanes)
+                       if lane is not None and lane.bucket is bl]
+                if not occ:
+                    continue
+                if bl.n_active > 0:
+                    with span("engine/step"):
+                        statics = bl.fleet.apply_statics
+                        bl.state = self._step_fn(bl.fleet.arrays, bl.state,
+                                                 **statics)
+                        note_program(self._step_fn, bl.fleet.arrays, bl.state,
+                                     **statics)
+                        self._account_sweeps(bl, occ)
+                with span("engine/readback"):
+                    active = np.asarray(bl.state.active)  # (slots,) flags only
+                frozen = [i for i in occ if not active[i]]
+                bl.n_active = int(active[occ].sum())
+                if frozen:
+                    with span("engine/retire"):
+                        done.extend(self._retire(bl, frozen))
+            self._unpin_idle()
+            self.ticks += 1
+            self.cache.advance_ticks(1)
+            if self.tracer is not None:
+                # first host-side timestamp after a lane's first step call —
+                # only when tracing is on (the stamp loop is pure host work,
+                # but a trace nobody asked for is still overhead)
+                t_first = self._clock()
+                for lane in self.lanes:
+                    if lane is not None and lane.req.first_tick_time == 0.0:
+                        lane.req.first_tick_time = t_first
+            # running *minimum* tick duration — the deadline-eviction lower
+            # bound for "one more tick".  A minimum (not a mean) is the
+            # safe estimator: compile-heavy first ticks must not inflate it
+            # and spuriously evict meetable requests; underestimating only
+            # delays eviction until the deadline has truly passed.  (An
+            # injected constant clock keeps this at 0, so tests evict
+            # exactly when the deadline passes.)
+            dur = self._clock() - t_tick0
+            self._est_tick_s = dur if self._est_tick_s == 0.0 else \
+                min(self._est_tick_s, dur)
+            self._m_ticks.inc()
+            self._m_tick_s.observe(dur)
+            self._m_queue.set(len(self.queue))
+            self._m_lanes.set(sum(l is not None for l in self.lanes))
+            if self.metrics is not None:
+                self.metrics.maybe_sample(self._clock())
         return done
 
     def _account_sweeps(self, bl: _BucketLanes, occ: List[int]) -> None:
@@ -749,7 +758,9 @@ class SolveEngine:
         jp = _next_pow2(j)
         rows_a = np.zeros(jp, np.int32)
         rows_a[:j] = rows
-        X, it, relres = self._gather_fn(bl.state, jnp.asarray(rows_a))
+        rows_d = jnp.asarray(rows_a)
+        X, it, relres = self._gather_fn(bl.state, rows_d)
+        note_program(self._gather_fn, bl.state, rows_d)
         X = np.asarray(X)[:j]
         it = np.asarray(it)[:j]
         relres = np.asarray(relres)[:j]

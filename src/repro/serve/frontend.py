@@ -46,6 +46,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from repro.obs.flight import NULL_FLIGHT
 from repro.obs.registry import NULL as _NULL_METRICS
+from repro.obs.tracing import span
 
 from .engine import EngineStats, SolveEngine, SolveRequest, make_request
 
@@ -296,7 +297,8 @@ class SolveFrontend:
             for fn, args, kw, cfut in control:
                 t0 = time.monotonic()
                 try:
-                    res = fn(*args, **kw)
+                    with span("frontend/control"):
+                        res = fn(*args, **kw)
                 except Exception as exc:
                     if not cfut.done():
                         cfut.set_exception(exc)
@@ -317,7 +319,8 @@ class SolveFrontend:
             try:
                 for req, fut in batch:
                     try:
-                        eng.submit(req)
+                        with span("frontend/submit"):
+                            eng.submit(req)
                     except Exception as exc:  # unknown graph / bad shape
                         self.failed += 1
                         self._m_failed.inc()
