@@ -259,7 +259,16 @@ def fleet_matvec(fa: FleetArrays, fidx: jnp.ndarray,
                  Y: jnp.ndarray) -> jnp.ndarray:
     """Per-lane Laplacian matvec: lane ``l`` multiplies by the operator
     of factor ``fidx[l]`` (edge lists gathered from the fleet stack).
-    Zero-weight padding edges contribute exactly zero."""
+    Zero-weight padding edges contribute exactly zero.  Where every
+    lane reads one row (``kernels.ops.shares_index``) its edge lists
+    are read once, and each edge's gathers and scatter-adds serve all
+    lanes through one shared index."""
+    from repro.kernels.ops import shares_index
+    if shares_index(fa.src.shape[0], Y.shape[0]):
+        s, d, ww = (jax.lax.dynamic_index_in_dim(a, fidx[0], keepdims=False)
+                    for a in (fa.src, fa.dst, fa.w))
+        diff = ww * (Y[:, s] - Y[:, d])
+        return jnp.zeros_like(Y).at[:, s].add(diff).at[:, d].add(-diff)
     src = fa.src[fidx]
     dst = fa.dst[fidx]
     w = fa.w[fidx]
@@ -279,7 +288,8 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
     ``kind`` of the family that owns the fleet:
 
     * ``"factor"`` — ``(G D Gᵀ)⁺`` apply: forward masked trisolve → D⁻¹
-      scale → backward masked trisolve, panels gathered per lane.  The
+      scale → backward masked trisolve, panels gathered per lane (once
+      for all lanes where they share a row: ``trisolve_fleet``).  The
       level bounds are bucket-wide maxima; lanes whose factor has fewer
       levels stop selecting rows early (masked no-op), so over-padding
       the bound never changes a lane's result.  Used by the randomized
@@ -308,7 +318,8 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
     """
     # deferred: kernels.ops pulls in kernels.ref → repro.core, so a
     # top-level import here is a cycle whenever kernels.ops loads first
-    from repro.kernels.ops import ell_spmv_fleet, trisolve_fleet
+    from repro.kernels.ops import ell_spmv_fleet, shares_index, \
+        trisolve_fleet
     if kind == "spmv":
         return ell_spmv_fleet(fa.fcols[fidx], fa.fvals[fidx], R)
     if kind != "factor":
@@ -318,17 +329,19 @@ def fleet_precondition(fa: FleetArrays, fidx: jnp.ndarray, R: jnp.ndarray,
     if active is not None:
         flv = jnp.where(active, flv, 1)
         blv = jnp.where(active, blv, 1)
+    # lanes that share a row share its plan and D⁻¹: read them once
+    row = fidx[:1] if shares_index(fa.fcols.shape[0], R.shape[0]) else fidx
     Y = trisolve_fleet(fa.fcols, fa.fvals, None, R, fidx=fidx,
                        n_levels=f_levels, lane_levels=flv, width=f_width,
-                       plan=(fa.forder[fidx], fa.fext[fidx], fa.fgend[fidx],
-                             fa.fptr[fidx, :f_levels + 1]))
+                       plan=(fa.forder[row], fa.fext[row], fa.fgend[row],
+                             fa.fptr[row, :f_levels + 1]))
     with jax.named_scope("pcg_update"):
-        Z = Y * fa.dinv[fidx]
+        Z = Y * fa.dinv[row]
     return trisolve_fleet(fa.bcols, fa.bvals, None, Z, fidx=fidx,
                           n_levels=b_levels, lane_levels=blv,
                           width=b_width,
-                          plan=(fa.border[fidx], fa.bext[fidx],
-                                fa.bgend[fidx], fa.bptr[fidx, :b_levels + 1]))
+                          plan=(fa.border[row], fa.bext[row],
+                                fa.bgend[row], fa.bptr[row, :b_levels + 1]))
 
 
 @jax.named_scope("pcg_update")

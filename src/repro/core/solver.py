@@ -370,6 +370,14 @@ class FactorFleet:
                     b_levels=self.b_levels, f_width=self.f_width,
                     b_width=self.b_width)
 
+    def index_path(self, lanes: int) -> str:
+        """The index path a fleet program over ``lanes`` lanes takes on
+        this stack (``kernels.ops.shares_index``): ``"shared"`` where
+        every lane reads one row, else ``"per_lane"``."""
+        from repro.kernels.ops import shares_index
+        return "shared" if shares_index(self.capacity, lanes) \
+            else "per_lane"
+
     @property
     def live_rows(self) -> int:
         return sum(r is not None and r() is not None for r in self._rows)
@@ -578,6 +586,9 @@ class PreconditionerHandle:
     _cache: "OrderedDict[Tuple, Callable]" = dataclasses.field(
         default_factory=OrderedDict)
     _sweeps: Optional[Tuple[Tuple, int]] = None
+    # solves dispatched, by index path (FactorFleet.index_path)
+    dispatches: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: {"shared": 0, "per_lane": 0})
 
     @property
     def n(self) -> int:
@@ -694,7 +705,10 @@ class PreconditionerHandle:
             else:
                 self._cache.move_to_end(key)
             args = (B, self.fleet.arrays, jnp.int32(self.fleet_row))
-            out = fn(*args)
+            path = self.fleet.index_path(1 if B.ndim == 1 else B.shape[0])
+            self.dispatches[path] += 1
+            with span(f"fleet/{path}"):
+                out = fn(*args)
             note_program(fn, *args)
             return out
 

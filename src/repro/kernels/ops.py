@@ -131,14 +131,30 @@ def ell_spmv_fleet(cols, vals, x):
     """Lane-batched ELL SpMV; cols/vals: [L, R, K], x: [L, n] → [L, R].
 
     An XLA gather-multiply-reduce on every backend: ``Y[l, i] =
-    Σ_k vals[l,i,k] · x[l, cols[l,i,k]]``.  The served path (every fleet
-    PCG apply and masked sweep) runs this form, so CPU tests exercise
-    the program the TPU runs.  ``ell_spmv_fleet_pallas`` computes the
-    same product as a Pallas kernel; the TPU compiler refuses its
-    random gather out of VMEM, so it is not on the served path."""
-    L, R, K = cols.shape
+    Σ_k vals[l,i,k] · x[l, cols[l,i,k]]``.  With cols/vals ``[R, K]``
+    every lane multiplies by the same rows (``vals[i,k] · x[l,
+    cols[i,k]]``), and one index fetches all ``L`` lanes' values.  The
+    served path (every fleet PCG apply and masked sweep) runs this form,
+    so CPU tests exercise the program the TPU runs.
+    ``ell_spmv_fleet_pallas`` computes the same product as a Pallas
+    kernel; the TPU compiler refuses its random gather out of VMEM, so
+    it is not on the served path."""
+    L = x.shape[0]
+    if cols.ndim == 2:
+        R, K = cols.shape
+        return _pairwise_sum(vals * x[:, cols.reshape(-1)].reshape(L, R, K))
+    _, R, K = cols.shape
     xg = jnp.take_along_axis(x, cols.reshape(L, R * K), axis=1)
     return _pairwise_sum(vals * xg.reshape(L, R, K))
+
+
+def shares_index(rows: int, lanes: int) -> bool:
+    """Whether the lanes of a fleet program can read only one row of its
+    factor stack: the stack holds ``rows == 1``, or the program has
+    ``lanes == 1``.  Such lanes walk one sweep plan in lockstep, so
+    :func:`trisolve_fleet` and ``pcg.fleet_matvec`` read that row once
+    and fetch every lane's values with one shared index."""
+    return rows == 1 or lanes == 1
 
 
 def _pairwise_sum(p):
@@ -286,39 +302,64 @@ def trisolve_fleet(cols, vals, level_of, y, *, n_levels: int,
     work (a few sweeps per level, instead of one sweep of ``n × K``
     slots), never a result.
 
+    Where every lane reads one row of the stack (:func:`shares_index`:
+    a one-row stack, or one lane) the lanes walk that row's plan in
+    lockstep, each stopping at its own level bound.  Each sweep then
+    reads its panel block once and fetches all lanes' values with one
+    shared index (``y[:, cols]``), and lanes past their bound keep their
+    values: the same sweeps and the same sums as the per-lane walk.
+
     ``plan`` is ``sweep_plan``'s ``(order, extent, group_end,
-    level_ptr)`` with ``level_ptr`` ``n_levels + 1`` wide.  With a plan,
-    the panels are stored in plan order — panel row ``j`` of a lane
-    holds the in-edges of row ``order[j]``, as a fleet stores them — so
-    a sweep reads one contiguous block of panel rows (a gather of rows
-    by index is a loop of single-row copies on a TPU).  Without one, the
-    plan is computed here and the row-indexed panels are put into plan
-    order first.  ``n_levels`` is the static bucket-wide ceiling;
-    ``lane_levels`` (optional, ``(L,)`` int32, traced) carries each
-    lane's *true* level count, and a lane stops there (a lane given 1
-    does nothing) — levels past it select no rows, so stopping early
-    only removes no-op sweeps."""
+    level_ptr)`` with ``level_ptr`` ``n_levels + 1`` wide, one row per
+    lane (or, where the lanes share a row, one row in all).  With a
+    plan, the panels are stored in plan order — panel row ``j`` of a
+    lane holds the in-edges of row ``order[j]``, as a fleet stores them
+    — so a sweep reads one contiguous block of panel rows (a gather of
+    rows by index is a loop of single-row copies on a TPU).  Without
+    one, the plan is computed here and the row-indexed panels are put
+    into plan order first.  ``n_levels`` is the static bucket-wide
+    ceiling; ``lane_levels`` (optional, ``(L,)`` int32, traced) carries
+    each lane's *true* level count, and a lane stops there (a lane
+    given 1 does nothing) — levels past it select no rows, so stopping
+    early only removes no-op sweeps."""
     L, n = y.shape
     K = cols.shape[2]
     C = n if width is None else max(1, min(int(width), n))
+    shared = shares_index(cols.shape[0], L)
     lane_of = jnp.arange(L) if fidx is None else fidx
+    if shared:
+        lane_of = lane_of[:1]             # the one row every lane reads
     if plan is None:
         if fidx is not None:
-            cols, vals, lane_of = cols[fidx], vals[fidx], jnp.arange(L)
+            cols, vals = cols[lane_of], vals[lane_of]
+            level_of = level_of[:cols.shape[0]]
+            lane_of = jnp.arange(cols.shape[0])
         plan = sweep_plan(vals, level_of, n_levels)
         cols = jnp.take_along_axis(cols, plan[0][:, :, None], axis=1)
         vals = jnp.take_along_axis(vals, plan[0][:, :, None], axis=1)
+    if shared:
+        plan = tuple(p[:1] for p in plan)
     order, extent, group_end, level_ptr = plan
-    classes = _k_classes(K)
+    P = order.shape[0]                    # plan rows: L, or 1 if shared
     first = jnp.concatenate(
-        [panel_class(extent), jnp.zeros((L, 1), jnp.int32)], axis=1)
+        [panel_class(extent), jnp.zeros((P, 1), jnp.int32)], axis=1)
     group_end = jnp.concatenate(
-        [group_end.astype(jnp.int32), jnp.full((L, 1), n, jnp.int32)],
+        [group_end.astype(jnp.int32), jnp.full((P, 1), n, jnp.int32)],
         axis=1)
     bound = jnp.full((L,), n_levels, jnp.int32) if lane_levels is None \
         else jnp.clip(lane_levels.astype(jnp.int32), 1, n_levels)
-    stop = jnp.take_along_axis(level_ptr.astype(jnp.int32),
-                               bound[:, None], axis=1)[:, 0]
+    level_ptr = level_ptr.astype(jnp.int32)
+    walk = _sweep_shared if shared else _sweep_per_lane
+    return walk(cols, vals, lane_of, y, order, first, group_end, level_ptr,
+                bound, classes=_k_classes(K), C=C)
+
+
+def _sweep_per_lane(cols, vals, lane_of, y, order, first, group_end,
+                    level_ptr, bound, *, classes, C):
+    """:func:`trisolve_fleet`'s walk where each lane has its own plan
+    row: per-lane starts, panel blocks and gathers."""
+    L, n = y.shape
+    stop = jnp.take_along_axis(level_ptr, bound[:, None], axis=1)[:, 0]
     lanes = jnp.arange(L)[:, None]
     cut = jnp.asarray(classes[:-1], jnp.int32)
 
@@ -365,8 +406,64 @@ def trisolve_fleet(cols, vals, level_of, y, *, n_levels: int,
             return branches[0](start, go, gend, y)
         return jax.lax.switch(run_cls, branches, start, go, gend, y)
 
-    start0 = level_ptr[:, 1].astype(jnp.int32)
+    start0 = level_ptr[:, 1]
     return jax.lax.while_loop(cond, body, (start0, y))[1]
+
+
+def _sweep_shared(cols, vals, lane_of, y, order, first, group_end,
+                  level_ptr, bound, *, classes, C):
+    """:func:`trisolve_fleet`'s walk where every lane reads stack row
+    ``lane_of[0]`` and its one plan row: one start, one panel block a
+    sweep, and gathers and scatters by indices shared by all lanes;
+    lanes past their level bound write their old values back."""
+    L, n = y.shape
+    order, first, group_end, level_ptr = (
+        a[0] for a in (order, first, group_end, level_ptr))
+    row = lane_of[0]
+    stop = level_ptr[bound]                                       # (L,)
+    cut = jnp.asarray(classes[:-1], jnp.int32)
+    rows_max = max(1, C)                  # the first class's row count
+
+    def branch(kc):
+        rows_k = max(1, C * classes[0] // kc)
+
+        def run(start, live, gend, y):
+            end = jnp.minimum(start + rows_k, gend)
+            at = jnp.minimum(start, n - rows_k)
+            pos = at + jnp.arange(rows_k, dtype=jnp.int32)
+            take = (pos >= start) & (pos < end)
+            rows = jnp.where(
+                take, jax.lax.dynamic_slice(order, (at,), (rows_k,)), n)
+            c = jax.lax.dynamic_slice(cols, (row, at, 0), (1, rows_k, kc))
+            v = jax.lax.dynamic_slice(vals, (row, at, 0), (1, rows_k, kc))
+            old = y[:, jnp.minimum(rows, n - 1)]
+            upd = jnp.where(live[:, None],
+                            old - ell_spmv_fleet(c[0], v[0], y), old)
+            pad = rows_max - rows_k
+            return (end, jnp.pad(rows, (0, pad), constant_values=n),
+                    jnp.pad(upd, ((0, 0), (0, pad))))
+        return run
+
+    branches = [branch(kc) for kc in classes]
+
+    def cond(carry):
+        start, _ = carry
+        return jnp.any(start < stop)
+
+    def body(carry):
+        start, y = carry
+        at = jnp.minimum(start, n)
+        cls = jnp.sum(first[at] > cut)
+        args = (start, start < stop, group_end[at], y)
+        if len(branches) == 1:
+            end, rows, upd = branches[0](*args)
+        else:
+            end, rows, upd = jax.lax.switch(cls, branches, *args)
+        # one scatter here, not one per branch: a branch that writes the
+        # carry makes the TPU compiler copy the whole carry in it
+        return end, y.at[:, rows].set(upd, mode="drop")       # pads drop
+
+    return jax.lax.while_loop(cond, body, (level_ptr[1], y))[1]
 
 
 def trisolve_panels(sched, b, flip: bool = False,

@@ -64,12 +64,15 @@ STAGES = ("route", "factor", "adopt", "queue", "first_tick", "solve")
 
 # Host spans of the program, as they nest: an engine tick and its
 # phases; the frontend driver's control calls and submissions;
-# ``PreconditionerHandle.solve``'s dispatch; construction's stages.
+# ``PreconditionerHandle.solve``'s dispatch; inside a step's or a
+# solve's, the fleet program's call by index path
+# (``FactorFleet.index_path``); construction's stages.
 PROGRAM_SPANS = (
     "engine/tick", "engine/admit", "engine/step", "engine/readback",
     "engine/retire",
     "frontend/control", "frontend/submit",
     "solver/solve",
+    "fleet/shared", "fleet/per_lane",
     "construct/pool", "construct/eliminate", "construct/finalize",
     "construct/schedules", "construct/pack", "construct/admit",
 )

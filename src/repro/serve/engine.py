@@ -210,6 +210,10 @@ class EngineStats:
     sweeps_skipped: int
     sweep_elements: int
     fleet_resyncs: int
+    # step dispatches by index path (FactorFleet.index_path): one fleet
+    # row or one lane → every lane reads its values by shared indices
+    shared_dispatches: int
+    per_lane_dispatches: int
     # -- scheduler decisions ------------------------------------------------
     policy: str
     max_skips: int
@@ -396,6 +400,7 @@ class SolveEngine:
         self.sweeps_skipped = 0
         self.sweep_elements = 0
         self.fleet_resyncs = 0
+        self.dispatches = {"shared": 0, "per_lane": 0}
 
         # -- observability (repro.obs) — instruments pre-bound here so
         # the tick loop only ever calls inc/set/observe on a child
@@ -434,6 +439,12 @@ class SolveEngine:
             "repro_engine_queue_wait_seconds",
             "admission queue wait (submit to lane grant)",
             labels=("replica",)).labels(replica=rep)
+        m_dispatch = reg.counter(
+            "repro_fleet_dispatch_total",
+            "fleet step dispatches, by index path",
+            labels=("replica", "path"))
+        self._m_dispatch = {path: m_dispatch.labels(replica=rep, path=path)
+                            for path in self.dispatches}
         self._obs_rep_label = rep
         # flight recorder + health monitor ride the same pre-bound
         # pattern: no-op callables when absent, one dict build per event
@@ -646,8 +657,12 @@ class SolveEngine:
                 if bl.n_active > 0:
                     with span("engine/step"):
                         statics = bl.fleet.apply_statics
-                        bl.state = self._step_fn(bl.fleet.arrays, bl.state,
-                                                 **statics)
+                        path = bl.fleet.index_path(self.slots)
+                        self.dispatches[path] += 1
+                        self._m_dispatch[path].inc()
+                        with span(f"fleet/{path}"):
+                            bl.state = self._step_fn(bl.fleet.arrays,
+                                                     bl.state, **statics)
                         note_program(self._step_fn, bl.fleet.arrays, bl.state,
                                      **statics)
                         self._account_sweeps(bl, occ)
@@ -868,6 +883,8 @@ class SolveEngine:
             sweeps_skipped=self.sweeps_skipped,
             sweep_elements=self.sweep_elements,
             fleet_resyncs=self.fleet_resyncs,
+            shared_dispatches=self.dispatches["shared"],
+            per_lane_dispatches=self.dispatches["per_lane"],
             policy=self.admission.name,
             max_skips=self.admission.max_skips,
             admitted_reqs=self.admitted_reqs,

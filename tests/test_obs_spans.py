@@ -15,7 +15,7 @@ from repro.data import graphs
 from repro.obs import tracing
 from repro.serve import SolveEngine, SolveFrontend
 
-NAMESPACES = ("engine/", "frontend/", "solver/", "construct/")
+NAMESPACES = ("engine/", "frontend/", "solver/", "fleet/", "construct/")
 TICK_PHASES = ("engine/admit", "engine/step", "engine/readback",
                "engine/retire")
 CONSTRUCTION = ("construct/pool", "construct/eliminate",
@@ -62,17 +62,22 @@ def _program_spans(d):
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """Construction, a direct solve and a served request, once with no
-    profiler session and once inside one."""
+    profiler session and once inside one.  Inside it the fleet also
+    holds a second graph of the bucket, admitted before the session, so
+    the engine's two lanes step on the per-lane index path (and the
+    one-column direct solve on the shared one)."""
     g = graphs.grid2d(12, 12, seed=3)
     b = _rhs(g.n, 0)
     plain = FactorCache(fill_slack=64)
     h = plain.factor(g, jax.random.key(0), graph_id="g")
     off = (jax.device_get(h.solve(b, tol=1e-6, maxiter=200)),
            _serve(plain, b))
+    cache = FactorCache(fill_slack=64)
+    cache.factor(graphs.grid2d(12, 12, seed=8), jax.random.key(1),
+                 graph_id="other")
     d = tmp_path_factory.mktemp("trace")
     _start(d)
     try:
-        cache = FactorCache(fill_slack=64)
         h_on = cache.factor(g, jax.random.key(0), graph_id="g")
         on = (jax.device_get(h_on.solve(b, tol=1e-6, maxiter=200)),
               _serve(cache, b))
@@ -95,6 +100,7 @@ def test_span_names_are_readable_by_the_trace_reduction():
 
 
 def test_trace_holds_every_program_span(traced):
+    assert traced["handle"].fleet.capacity == 2
     names = {n for _, n, _, _ in traced["spans"]}
     assert names <= set(tracing.PROGRAM_SPANS)
     assert names == set(tracing.PROGRAM_SPANS)
@@ -114,6 +120,13 @@ def test_spans_nest_as_listed(traced):
     assert all(a[1] <= b[0] for a, b in zip(stages, stages[1:]))
     solve, = [(s, e) for _, n, s, e in spans if n == "solver/solve"]
     assert stages[-1][1] <= solve[0]
+    # each fleet program call lies inside a step's or a solve's dispatch
+    calls = [(ln, s, e) for ln, n, s, e in spans
+             if n in ("engine/step", "solver/solve")]
+    for ln, n, s, e in spans:
+        if n.startswith("fleet/"):
+            assert any(ln == cl and cs <= s and e <= ce
+                       for cl, cs, ce in calls), n
 
 
 def test_answers_bit_identical_with_profiler_on_and_off(traced):

@@ -376,6 +376,54 @@ def test_engine_shape_bucket_mega_batch(fleet):
                               np.asarray(ref.iters))
 
 
+@pytest.mark.parametrize("graphs_in_cache", [1, 2])
+def test_engine_counts_dispatches_by_index_path(graphs_in_cache):
+    """An 8-slot engine over a one-graph cache (a one-row fleet) steps on
+    the shared-index path only, and each answer is bit-identical to
+    ``handle.solve`` of its right-hand side; over two graphs of one
+    bucket (a two-row fleet) it steps on the per-lane path, with the
+    same answers.  The counts show in ``stats()``, in the metrics
+    registry and on the handle."""
+    from repro.obs.registry import MetricsRegistry
+    gs = [graphs.grid2d(12, 12, seed=3), graphs.grid2d(12, 12, seed=8)]
+    gs = gs[:graphs_in_cache]
+    c = FactorCache(chunk=32, fill_slack=64)
+    c.factor_batched(gs, [jax.random.key(i) for i in range(len(gs))],
+                     graph_ids=[f"g{i}" for i in range(len(gs))])
+    hs = [c.get(f"g{i}") for i in range(len(gs))]
+    assert hs[0].fleet.capacity == graphs_in_cache
+    reg = MetricsRegistry()
+    eng = SolveEngine(c, slots=8, iters_per_tick=8, metrics=reg)
+    rng = np.random.default_rng(17)
+    reqs = [SolveRequest(rid=i, graph_id=f"g{i % len(gs)}",
+                         b=_rhs(rng, gs[0].n, 1), tol=1e-6, maxiter=300)
+            for i in range(8)]
+    for r in reqs:
+        eng.submit(r)
+    assert len(eng.run_until_drained()) == len(reqs)
+    st = eng.stats()
+    path = "shared" if graphs_in_cache == 1 else "per_lane"
+    counts = {"shared": st.shared_dispatches,
+              "per_lane": st.per_lane_dispatches}
+    assert counts[path] > 0 and sum(counts.values()) == counts[path]
+    m = reg.counter("repro_fleet_dispatch_total",
+                    labels=("replica", "path"))
+    assert {p: m.labels(replica="solo", path=p).value
+            for p in counts} == counts
+    for i, r in enumerate(reqs):
+        h = hs[i % len(gs)]
+        ref = h.solve(jnp.asarray(r.b), tol=r.tol, maxiter=r.maxiter)
+        assert r.converged
+        assert np.array_equal(r.x, np.asarray(ref.x))
+        assert np.array_equal(np.atleast_1d(r.iters),
+                              np.atleast_1d(np.asarray(ref.iters)))
+        assert np.array_equal(np.atleast_1d(r.relres),
+                              np.atleast_1d(np.asarray(ref.relres)))
+    # one-column direct solves read one row whatever the fleet holds
+    assert hs[0].dispatches["shared"] >= 1
+    assert hs[0].dispatches["per_lane"] == 0
+
+
 def test_engine_scatter_admission_matches_host_oracle(fleet, cache):
     """Satellite: the jitted scatter admission leaves bit-identical
     per-lane carries to a host-stacked oracle that initializes the same

@@ -245,6 +245,86 @@ def test_fleet_trisolve_panel_classes_are_bit_exact():
                                    atol=1e-4)
 
 
+def _shared_index_fleet(rng, n=96, K=32):
+    """A two-row fleet stack of random unit-triangular factors of
+    several panel classes (panels in plan order, as a fleet stores
+    them, level pointers over the common level bound) and random
+    Laplacian edge lists with zero-weight padding."""
+    from repro.core.pcg import FleetArrays
+    from repro.core.trisolve import sweep_plan_np
+    facs = [_random_panels(rng, n, K, 9) for _ in range(2)]
+    nl = max(int(lv.max()) for _, _, lv, _ in facs) + 1
+    rows = []
+    for cols, vals, lv, _ in facs:
+        plan = sweep_plan_np(lv, (vals != 0).sum(axis=1), nl)
+        rows.append((cols[plan[0]], vals[plan[0]]) + tuple(plan))
+    m = 300
+    src = rng.integers(0, n, (2, m)).astype(np.int32)
+    dst = rng.integers(0, n, (2, m)).astype(np.int32)
+    w = rng.uniform(0.5, 2.0, (2, m)).astype(np.float32)
+    w[:, -40:] = 0.0
+    stack = [jnp.asarray(np.stack(a)) for a in zip(*rows)]
+    fa = FleetArrays(*([None] * len(FleetArrays._fields)))._replace(
+        src=jnp.asarray(src), dst=jnp.asarray(dst), w=jnp.asarray(w))
+    return stack, fa, nl
+
+
+@pytest.mark.parametrize("lanes,rows", [(1, 1), (1, 2), (8, 1), (8, 2)])
+def test_fleet_shared_index_path_is_bit_exact(lanes, rows):
+    """Lanes that can read only one fleet row (a one-row stack, or one
+    lane) take the shared-index path of ``trisolve_fleet`` and
+    ``fleet_matvec``: one panel block a sweep and one index for every
+    lane's value.  Each lane's output is bit-identical to the per-lane
+    path's for the same lane, with lanes at different level bounds,
+    frozen and idle lanes, and several panel classes."""
+    from repro.core.pcg import fleet_matvec
+    rng = np.random.default_rng(11)
+    (cols, vals, order, ext, gend, ptr), fa, nl = _shared_index_fleet(rng)
+    n = cols.shape[1]
+    # eight lanes on both rows: full depth, shallower bounds, one frozen
+    # (bound 1) and one idle (zero, bound 1)
+    fidx8 = np.array([0, 1, 0, 1, 0, 0, 1, 0], np.int32)
+    if rows == 1:
+        fidx8[:] = 0
+    bound8 = np.array([nl, 4, 1, nl, 7, 1, nl, 2], np.int32)
+    y8 = rng.normal(size=(8, n)).astype(np.float32)
+    y8[5] = 0.0
+
+    def solve(stack_rows, fidx, y, bound):
+        ix = jnp.asarray(fidx)
+        plan = tuple(a[:stack_rows][ix] for a in (order, ext, gend, ptr))
+        t = kops.trisolve_fleet(cols[:stack_rows], vals[:stack_rows], None,
+                                jnp.asarray(y), fidx=ix, n_levels=nl,
+                                lane_levels=jnp.asarray(bound), width=5,
+                                plan=plan)
+        part = jax.tree_util.tree_map(
+            lambda a: a if a is None else a[:stack_rows], fa)
+        return np.asarray(t), np.asarray(fleet_matvec(part, ix,
+                                                      jnp.asarray(y)))
+
+    # the per-lane path: eight lanes on the two-row stack
+    assert not kops.shares_index(2, 8)
+    want = solve(2, fidx8, y8, bound8)
+    if (lanes, rows) == (8, 2):
+        # the per-lane path against each lane alone on the shared path
+        assert kops.shares_index(2, 1)
+        got = [solve(2, fidx8[j:j + 1], y8[j:j + 1], bound8[j:j + 1])
+               for j in range(8)]
+        got = tuple(np.concatenate(a) for a in zip(*got))
+    else:
+        pick = slice(None) if lanes == 8 else slice(1, 2)
+        assert kops.shares_index(rows, lanes)
+        got = solve(rows, fidx8[pick], y8[pick], bound8[pick])
+        want = tuple(a[pick] for a in want)
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    # frozen and idle lanes keep their values; a live lane moved
+    t = got[0] if lanes == 8 else None
+    if t is not None:
+        assert np.array_equal(t[2], y8[2]) and not t[5].any()
+        assert not np.array_equal(t[0], y8[0])
+
+
 def test_pallas_panel_trisolve_matches_host(g_small):
     f = factorize_sequential(g_small, KEY)
     fwd_h, bwd_h = build_schedules(f)

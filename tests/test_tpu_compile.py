@@ -100,6 +100,12 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _graphs(rows, tag=""):
+    """``GRAPHS`` as parameters with a fleet row count, ids tagged."""
+    return [pytest.param(*g, rows, id="-".join(map(str, g)) + tag)
+            for g in GRAPHS]
+
+
 @pytest.mark.parametrize("name,n_pad,m_pad", GRAPHS)
 def test_ell_spmv_fleet_compiles(one_chip, name, n_pad, m_pad):
     c = _compile(ell_spmv_fleet,
@@ -110,34 +116,44 @@ def test_ell_spmv_fleet_compiles(one_chip, name, n_pad, m_pad):
     assert "tpu_custom_call" not in c.as_text()
 
 
-@pytest.mark.parametrize("name,n_pad,m_pad", GRAPHS)
-def test_trisolve_fleet_compiles(one_chip, name, n_pad, m_pad):
+@pytest.mark.parametrize("name,n_pad,m_pad,rows",
+                         _graphs(None) + _graphs(1, "-one_row"))
+def test_trisolve_fleet_compiles(one_chip, name, n_pad, m_pad, rows):
+    """Lanes on their own panels (per-lane path), and eight lanes on a
+    one-row fleet stack (shared-index path)."""
     s = partial(_spec, one_chip)
+    P = LANES if rows is None else rows
 
-    def solve(cols, vals, level_of, y, lane_levels, plan):
-        return trisolve_fleet(cols, vals, level_of, y, n_levels=LEVELS,
+    def solve(cols, vals, y, lane_levels, plan, fidx):
+        return trisolve_fleet(cols, vals, None, y, n_levels=LEVELS,
                               lane_levels=lane_levels,
-                              width=WIDTHS[name][0], plan=plan)
+                              width=WIDTHS[name][0], plan=plan, fidx=fidx)
 
-    lane_rows = s((LANES, n_pad), jnp.int32)
-    c = _compile(solve, s((LANES, n_pad, K), jnp.int32),
-                 s((LANES, n_pad, K), jnp.float32),
-                 lane_rows, s((LANES, n_pad), jnp.float32),
+    plan_rows = s((P, n_pad), jnp.int32)
+    c = _compile(solve, s((P, n_pad, K), jnp.int32),
+                 s((P, n_pad, K), jnp.float32),
+                 s((LANES, n_pad), jnp.float32),
                  s((LANES,), jnp.int32),
-                 (lane_rows, lane_rows, lane_rows,
-                  s((LANES, LEVELS + 1), jnp.int32)))
+                 (plan_rows, plan_rows, plan_rows,
+                  s((P, LEVELS + 1), jnp.int32)),
+                 None if rows is None else s((LANES,), jnp.int32))
     # one sweep program per panel class (8 … K slots)
     assert c.as_text().count("conditional") >= 1
 
 
-@pytest.mark.parametrize("kind", ["factor", "spmv"])
+@pytest.mark.parametrize("kind,rows", [
+    pytest.param("factor", 2, id="factor"),
+    pytest.param("spmv", 2, id="spmv"),
+    pytest.param("factor", 1, id="factor-one_row")])
 @pytest.mark.parametrize("name,n_pad,m_pad", GRAPHS)
-def test_pcg_fleet_step_compiles(one_chip, name, n_pad, m_pad, kind):
+def test_pcg_fleet_step_compiles(one_chip, name, n_pad, m_pad, kind, rows):
+    """Eight lanes on a two-row fleet (per-lane path) and on a one-row
+    fleet (shared-index path)."""
     levels, (fw, bw) = (LEVELS, WIDTHS[name]) if kind == "factor" \
         else (1, (1, 1))
     step = partial(pcg_fleet_step, k=8, f_levels=levels, b_levels=levels,
                    kind=kind, f_width=fw, b_width=bw)
-    c = _compile(step, _fleet_specs(one_chip, n_pad, m_pad),
+    c = _compile(step, _fleet_specs(one_chip, n_pad, m_pad, rows),
                  _state_specs(one_chip, n_pad))
     assert c.memory_analysis() is not None
 
